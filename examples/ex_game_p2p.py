@@ -117,6 +117,9 @@ def main() -> int:
         "the 60fps frame-budget hit rate as one JSON line",
     )
     args = ap.parse_args()
+    from ggrs_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     trace = None
     if args.trace:
         import json as _json

@@ -55,6 +55,9 @@ def main() -> int:
         "on device (zero readbacks until the final check)",
     )
     args = ap.parse_args()
+    from ggrs_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     if args.fused and (args.host or args.native or args.device_verify):
         ap.error(
@@ -136,11 +139,11 @@ def main() -> int:
 
 def run_fused(args) -> int:
     """The fully-fused session: batches of 60 ticks per device dispatch."""
+    import jax
     import numpy as np
 
     from ggrs_tpu.models import Arena, ExGame, Swarm
     from ggrs_tpu.tpu import TpuSyncTestSession
-    from ggrs_tpu.utils.barrier import true_barrier
 
     model_cls = {"arena": Arena, "swarm": Swarm}.get(args.model, ExGame)
     sess = TpuSyncTestSession(
@@ -161,7 +164,7 @@ def run_fused(args) -> int:
         for start in range(0, args.frames, batch):
             sess.advance_frames(script[start : start + batch])
         sess.check()
-        true_barrier(sess.carry["state"])
+        jax.block_until_ready(sess.carry["state"])
     except MismatchedChecksum as exc:
         print(f"DESYNC: {exc}")
         return 1

@@ -46,6 +46,9 @@ def main() -> int:
     ap.add_argument("--postmortem", metavar="HIST",
                     help="JSON {frame: checksum} peer history to compare")
     args = ap.parse_args()
+    from ggrs_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     from ggrs_tpu.models import Arena, ExGame, Swarm
     from ggrs_tpu.ops.fixed_point import combine_checksum

@@ -24,26 +24,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..ops.fixed_point import GOLDEN32
 
-# jax moved shard_map from jax.experimental to the top level; depending on
-# the installed version only one spelling exists (0.4.x raises
-# AttributeError on jax.shard_map through its deprecation machinery). THE
-# one compat alias — every shard_map consumer in the package imports it
-# from here instead of hardcoding a spelling.
-try:
-    shard_map = jax.shard_map
-except AttributeError:  # pragma: no cover - version-dependent
-    from jax.experimental.shard_map import shard_map as _experimental_shard_map
-
-    def shard_map(f, **kwargs):
-        # the experimental module predates the check_rep -> check_vma
-        # rename: translate so call sites can use the modern spelling
-        # (dropping the flag instead is NOT equivalent — legacy
-        # check_rep=True hits NotImplementedError on these bodies)
-        if "check_vma" in kwargs:
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-        return _experimental_shard_map(f, **kwargs)
-
-
 def state_specs(state):
     """PartitionSpec pytree for a game-state pytree: entity arrays split
     over the `entity` axis on axis 0, scalars replicated. THE sharded-state
@@ -123,7 +103,7 @@ def sharded_checksum(state, mesh: Mesh, keys=None):
     flat_specs = {k: P("entity") for k in keys}
 
     @partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(flat_specs, P()),
         out_specs=(P(), P()),
@@ -266,7 +246,7 @@ def stacked_sharded_checksum(stacked_state, mesh: Mesh, keys=None):
     }
 
     @partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(in_state_specs, P("session")),
         out_specs=(P("session"), P("session")),

@@ -21,8 +21,8 @@ injectable, seeded and replayable:
     harvest/readback, mailbox staging, checkpoint write) plus direct
     state corruption (`inject_slot_bitflip`).
 
-Fault kinds (docs/DESIGN.md "Device fault domains" has the taxonomy
-table and each kind's recovery ladder):
+Fault kinds (docs/DESIGN.md "Device fault domains" has the table
+of kinds and each kind's recovery ladder):
 
   dispatch_raise     a dispatch/drive raises DeviceDispatchFailed
                      BEFORE executing (worlds untouched) — one-shot

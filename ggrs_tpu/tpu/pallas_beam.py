@@ -357,9 +357,7 @@ class ShardedPallasBeamRollout:
             parts_lo = jax.lax.psum(parts_lo, "entity")
             return inner.finish(outs, parts_hi, parts_lo, anchor["frame"], L)
 
-        from ..parallel.sharded import shard_map as _shard_map
-
-        shard_fn = _shard_map(
+        shard_fn = jax.shard_map(
             body,
             mesh=self.mesh,
             in_specs=(s_specs, P()),

@@ -521,9 +521,7 @@ class ShardedPallasTickCore:
             new_state["frame"] = state_frame
             return new_ring, new_state, verify, his, los
 
-        from ..parallel.sharded import shard_map as _shard_map
-
-        shard_fn = _shard_map(
+        shard_fn = jax.shard_map(
             body,
             mesh=self.mesh,
             in_specs=(r_specs, s_specs, P(), verify_specs),

@@ -898,9 +898,7 @@ class ShardedPallasTiledCore:
             (carry, _red), _ = jax.lax.scan(tick, (carry, red0), inputs)
             return carry
 
-        from ..parallel.sharded import shard_map as _shard_map
-
-        shard_fn = _shard_map(
+        shard_fn = jax.shard_map(
             body,
             mesh=self.mesh,
             in_specs=(specs, P()),

@@ -8,8 +8,7 @@
 #   1. native build           (g++ -> ggrs_tpu/native/libggrs_native.so)
 #   2. full pytest suite      (8-device virtual CPU mesh; ~15 min)
 #   3. UBSAN pass             (sanitized rebuild + the native/wire tests)
-#   4. README perf table      (gen_perf_table --check: table == bench JSON)
-#   5. multi-chip dryrun      (the driver's compile/execute gate, 8 devices)
+#   4. multi-chip dryrun      (the driver's compile/execute gate, 8 devices)
 #
 # Any failure fails the script. Usage: scripts/check.sh [--fast|--tier1|--obs-smoke]
 #   --fast skips the UBSAN rebuild+retest and the dryrun (inner-loop use).
@@ -245,45 +244,45 @@ fi
 FAST=0
 [ "${1:-}" = "--fast" ] && FAST=1
 
-echo "== [0/5] static analysis + sanitizer smoke =="
+echo "== [0/4] static analysis + sanitizer smoke =="
 run_lint
 
-echo "== [1/5] native build =="
+echo "== [1/4] native build =="
 make -C native
 
-echo "== [2/5] pytest (full suite, virtual 8-device CPU mesh) =="
+echo "== [2/4] pytest (full suite, virtual 8-device CPU mesh) =="
 python -m pytest tests/ -q
 
-echo "== [2b/5] chaos smoke (fleet operations end to end) =="
+echo "== [2b/4] chaos smoke (fleet operations end to end) =="
 JAX_PLATFORMS=cpu python scripts/chaos_smoke.py
 
-echo "== [2c/5] spec smoke (speculative bubble-filling end to end) =="
+echo "== [2c/4] spec smoke (speculative bubble-filling end to end) =="
 GGRS_SANITIZE=1 JAX_PLATFORMS=cpu \
   XLA_FLAGS=--xla_force_host_platform_device_count=8 \
   python scripts/spec_smoke.py
 
-echo "== [2d/5] fleet smoke (multi-process control plane, real SIGKILL) =="
+echo "== [2d/4] fleet smoke (multi-process control plane, real SIGKILL) =="
 JAX_PLATFORMS=cpu python scripts/fleet_smoke.py
 
-echo "== [2e/5] resident smoke (device mailbox + while_loop driver) =="
+echo "== [2e/4] resident smoke (device mailbox + while_loop driver) =="
 GGRS_SANITIZE=1 JAX_PLATFORMS=cpu python scripts/resident_smoke.py
 
-echo "== [2f/5] fault smoke (device fault domains end to end) =="
+echo "== [2f/4] fault smoke (device fault domains end to end) =="
 GGRS_SANITIZE=1 JAX_PLATFORMS=cpu python scripts/fault_smoke.py
 
-echo "== [2g/5] journal smoke (durable journal + journal-only recovery) =="
+echo "== [2g/4] journal smoke (durable journal + journal-only recovery) =="
 JAX_PLATFORMS=cpu python scripts/journal_smoke.py
 
-echo "== [2h/5] learn smoke (journal -> train -> registry -> hot-swap serve) =="
+echo "== [2h/4] learn smoke (journal -> train -> registry -> hot-swap serve) =="
 GGRS_SANITIZE=1 JAX_PLATFORMS=cpu \
   XLA_FLAGS=--xla_force_host_platform_device_count=8 \
   python scripts/learn_smoke.py
 
-echo "== [2i/5] endpoint smoke (vectorized protocol plane + crossover) =="
+echo "== [2i/4] endpoint smoke (vectorized protocol plane + crossover) =="
 GGRS_SANITIZE=1 JAX_PLATFORMS=cpu python scripts/endpoint_smoke.py
 
 if [ "$FAST" = "0" ]; then
-  echo "== [3/5] UBSAN build + native/wire tests =="
+  echo "== [3/4] UBSAN build + native/wire tests =="
   make -C native sanitize
   python -m pytest tests/test_native.py tests/test_native_endpoint.py \
     tests/test_native_input_queue.py tests/test_native_session.py \
@@ -291,23 +290,15 @@ if [ "$FAST" = "0" ]; then
     tests/test_soak_parity.py -q
   make -C native  # restore the normal build
 else
-  echo "== [3/5] UBSAN pass skipped (--fast) =="
-fi
-
-echo "== [4/5] README perf table in sync with the committed bench JSON =="
-LATEST_BENCH=$(ls -1 BENCH_local_r*.json 2>/dev/null | sort | tail -1)
-if [ -n "$LATEST_BENCH" ]; then
-  python scripts/gen_perf_table.py "$LATEST_BENCH" --check
-else
-  echo "no committed BENCH_local_r*.json; skipping table check"
+  echo "== [3/4] UBSAN pass skipped (--fast) =="
 fi
 
 if [ "$FAST" = "0" ]; then
-  echo "== [5/5] multi-chip dryrun (8 virtual CPU devices) =="
+  echo "== [4/4] multi-chip dryrun (8 virtual CPU devices) =="
   JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
     python -c "import __graft_entry__ as g; g.dryrun_multichip(8)"
 else
-  echo "== [5/5] dryrun skipped (--fast) =="
+  echo "== [4/4] dryrun skipped (--fast) =="
 fi
 
 echo "== check OK =="

@@ -26,17 +26,6 @@ from ggrs_tpu.utils.clock import FakeClock
 NUM_PLAYERS = 2
 ENTITIES = 128  # divisible by the 4-wide entity axis of the 8-device mesh
 
-# History: on jax versions without a top-level jax.shard_map (< 0.6),
-# four sharded parity tests here were KNOWN-RED and skip-gated. The root
-# cause was never the jax.experimental.shard_map compat shim in
-# ggrs_tpu/parallel/sharded.py: jax 0.4.x GSPMD miscompiles
-# `sum(concatenate([...]))` of an entity-sharded operand on a multi-axis
-# mesh into an all-reduce over EVERY mesh axis, so a world replicated
-# over the 2-wide `beam` axis reported exactly 2x the true checksum. The
-# models' `_checksum_generic` now computes per-key partial sums with
-# global word offsets (ops/fixed_point.weighted_checksum_parts —
-# bit-identical totals, no concatenate), and all four tests pass under
-# the shim on jax 0.4.37 as well as under the native jax.shard_map.
 import jax  # noqa: F401  (kept: the fixture and parity tests poke jax)
 
 
