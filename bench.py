@@ -1661,7 +1661,7 @@ def bench_p2p4_rollback(rounds=12, burst=12, lazy_ticks=0, mesh_devices=0,
     span_ms = 0.0
     for name, s in GLOBAL_TRACER.stats.items():
         if name.startswith("tpu/fused") or name.startswith("tpu/beam"):
-            span_ms += s.total_ms
+            span_ms += s.sum
     dispatch_ms_per_tick = span_ms / max(n_ticks, 1)
     mean_tick_ms = float(np.mean(tick_total_s)) * 1000.0
     peer_ms_per_tick = peer_phase_s / max(n_ticks, 1) * 1000.0
@@ -1677,12 +1677,12 @@ def bench_p2p4_rollback(rounds=12, burst=12, lazy_ticks=0, mesh_devices=0,
         # directly-spanned request parsing (the derived tick_host_parse_ms
         # below is the residual, which also absorbs scheduling jitter)
         "tick_parse_span_ms": round(
-            (parse_span.total_ms / max(n_ticks, 1)) if parse_span else 0.0, 4
+            (parse_span.sum / max(n_ticks, 1)) if parse_span else 0.0, 4
         ),
         # async fence stalls: the device time the pipeline FAILED to hide
         # behind host work (0 in eager mode, where nothing fences)
         "async_fence_ms_per_tick": round(
-            (fence_span.total_ms / max(n_ticks, 1)) if fence_span else 0.0, 4
+            (fence_span.sum / max(n_ticks, 1)) if fence_span else 0.0, 4
         ),
         "tick_mean_ms": round(mean_tick_ms, 4),
         # inside tick_mean: the session's own advance (pump + sync layer)
@@ -1954,7 +1954,7 @@ def _capacity_arm(batched, sessions, ticks, entities, seed, floor_reps=600):
     assert not desyncs, f"capacity arm desynced: {desyncs[:3]}"
     span = GLOBAL_TRACER.stats.get("host/pump")
     GLOBAL_TRACER.enabled = was_enabled
-    traffic_ms = span.total_ms if span is not None else 0.0
+    traffic_ms = span.sum if span is not None else 0.0
 
     # protocol-plane floor: quiescent passes, best of two rounds (round
     # one warms caches; the virtual clock is frozen so nothing expires)

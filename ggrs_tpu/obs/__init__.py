@@ -27,6 +27,9 @@ from .metrics import (
 )
 from .recorder import FlightEvent, FlightRecorder, jsonable
 from .telemetry import GLOBAL_TELEMETRY, Telemetry, enable_global_telemetry
+from .gc_pause import install_gc_watch
+
+install_gc_watch()
 
 __all__ = [
     "DISPATCH_DEPTH_BUCKETS",

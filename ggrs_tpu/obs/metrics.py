@@ -213,6 +213,10 @@ class _Instrument:
             self._bound[key] = bound
         return bound
 
+    def bound_children(self) -> Dict[Tuple[str, ...], object]:
+        """Every child created so far, label tuple -> bound child."""
+        return {key: self.labels(*key) for key in self._children}
+
     # unlabeled convenience: metric.inc()/set()/observe() act on the () child
     def _default(self):
         if self.labelnames:

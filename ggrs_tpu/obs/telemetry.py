@@ -6,11 +6,10 @@ like `GLOBAL_TRACER` — every instrumentation site in the stack guards with
 read and a branch, nothing else. Enabling mid-session is legal: instruments
 are pre-bound eagerly, so counters simply start moving.
 
-`snapshot()` folds the GLOBAL_TRACER span stats into the same structure so
-there is ONE report (metrics + flight-recorder tail + tracer spans), not a
-telemetry report and a separate tracing report. The Prometheus exporter
-renders tracer spans as synthetic `ggrs_tracer_span_*` metrics for the
-same reason.
+Tracer spans are a registry histogram (`ggrs_span_ms{span}`), so both
+exporters carry them like any other instrument; `snapshot()` adds a
+`tracer` section, a per-span summary of that histogram, so there is ONE
+report (metrics + flight-recorder tail + tracer spans).
 
 On `DesyncDetected` the P2P session calls `write_desync_forensics()`: the
 divergent frame, both checksums, the last-N flight-recorder events and the
@@ -25,7 +24,7 @@ import os
 import time
 from typing import Any, Dict, List, Optional
 
-from .metrics import MetricsRegistry, _escape_label
+from .metrics import MetricsRegistry
 from .recorder import DEFAULT_CAPACITY, FlightRecorder, jsonable
 
 
@@ -62,7 +61,8 @@ class Telemetry:
 
     def snapshot(self, tracer=None, recorder_tail: Optional[int] = None) -> dict:
         """One structured, JSON-serializable report: metrics + flight
-        recorder + tracer spans (GLOBAL_TRACER by default)."""
+        recorder + a per-span summary of the tracer's ggrs_span_ms
+        (GLOBAL_TRACER by default)."""
         if tracer is None:
             from ..utils.tracing import GLOBAL_TRACER as tracer
         return {
@@ -71,39 +71,21 @@ class Telemetry:
             "metrics": self.registry.snapshot(),
             "events": self.recorder.to_json(recorder_tail),
             "tracer": {
-                name: {
+                path: {
                     "count": s.count,
-                    "mean_ms": s.mean_ms,
-                    "max_ms": s.max_ms,
-                    "total_ms": s.total_ms,
+                    "mean_ms": s.sum / s.count,
+                    "total_ms": s.sum,
                 }
-                for name, s in sorted(tracer.stats.items())
+                for path, s in sorted(tracer.stats.items())
             },
         }
 
     def to_json(self, tracer=None, indent: Optional[int] = None) -> str:
         return json.dumps(self.snapshot(tracer), indent=indent)
 
-    def prometheus(self, tracer=None) -> str:
-        """Prometheus text exposition format (0.0.4), tracer spans folded
-        in as ggrs_tracer_span_{count,total_ms,max_ms} series."""
-        if tracer is None:
-            from ..utils.tracing import GLOBAL_TRACER as tracer
-        lines: List[str] = self.registry.prometheus_lines()
-        if tracer.stats:
-            spans = sorted(tracer.stats.items())
-            for suffix, kind, value_of in (
-                ("count", "counter", lambda s: s.count),
-                ("total_ms", "counter", lambda s: s.total_ms),
-                ("max_ms", "gauge", lambda s: s.max_ms),
-            ):
-                name = f"ggrs_tracer_span_{suffix}"
-                lines.append(f"# TYPE {name} {kind}")
-                for span, s in spans:
-                    lines.append(
-                        f'{name}{{span="{_escape_label(span)}"}} {value_of(s)}'
-                    )
-        return "\n".join(lines) + "\n"
+    def prometheus(self) -> str:
+        """Prometheus text exposition format (0.0.4)."""
+        return "\n".join(self.registry.prometheus_lines()) + "\n"
 
     # ------------------------------------------------------------------
     # desync forensics

@@ -44,7 +44,6 @@ loop").
 from __future__ import annotations
 
 import os
-import time as _time
 from collections import deque
 from typing import Any, Dict, List, Optional, Tuple
 
@@ -1037,25 +1036,26 @@ class SessionHost:
         # A HarvestTimeout (fault seam / real readback stall) is
         # transient by contract: the values still exist on device, so
         # this tick's drain is skipped and the next pass resolves them.
-        t_drain = _time.perf_counter() if tel.enabled else 0.0
-        try:
-            if self.fault_seam is not None:
-                self.fault_seam.before_harvest("drain")
-            self.device.ledger.drain_ready()
-            self.device.poll_retired()
-        except HarvestTimeout:
-            self.harvest_timeouts += 1
-            if tel.enabled:
-                tel.record("harvest_timeout", op="drain")
-        self._resolve_audits()
-        if tel.enabled:
-            self._m_tax_drain.observe(
-                (_time.perf_counter() - t_drain) * 1000.0
-            )
+        with GLOBAL_TRACER.span(
+            "host/drain", absolute=True,
+            feed=self._m_tax_drain if tel.enabled else None,
+        ):
+            try:
+                if self.fault_seam is not None:
+                    self.fault_seam.before_harvest("drain")
+                self.device.ledger.drain_ready()
+                self.device.poll_retired()
+            except HarvestTimeout:
+                self.harvest_timeouts += 1
+                if tel.enabled:
+                    tel.record("harvest_timeout", op="drain")
+            self._resolve_audits()
 
         # 2. advance ready sessions and stage their rows
-        t_parse = _time.perf_counter() if tel.enabled else 0.0
-        with GLOBAL_TRACER.span("host/advance", absolute=True):
+        with GLOBAL_TRACER.span(
+            "host/advance", absolute=True,
+            feed=self._m_tax_parse if tel.enabled else None,
+        ):
             for lane in list(self._lanes.values()):
                 if not self._lane_ready(lane):
                     continue
@@ -1121,10 +1121,6 @@ class SessionHost:
                     # host mid-stage)
                     lane.queued_since_tick = self._tick_index
                     self._ready.append(lane.key)
-        if tel.enabled:
-            self._m_tax_parse.observe(
-                (_time.perf_counter() - t_parse) * 1000.0
-            )
 
         # 2b. durable journal: drain each journaled lane's confirmed
         # frontier into its segment WAL (a host-side pure observer —
@@ -1135,9 +1131,10 @@ class SessionHost:
         # 3. dispatch megabatches under the device-window budget (env
         # blocks still dispatch synchronously; in resident mode session
         # lanes never enter the ready queue, so this is env-only there)
-        self._pump_device()
-        if self.resident_active:
-            self._resident_pump()
+        with GLOBAL_TRACER.span("host/dispatch", absolute=True):
+            self._pump_device()
+            if self.resident_active:
+                self._resident_pump()
 
         # 3b. speculative bubble-filling: draft the input-starved lanes'
         # futures into the device (one vmapped rollout batch riding the
@@ -1180,12 +1177,13 @@ class SessionHost:
                 if tel.enabled:
                     tel.record("host_admission_restored")
 
-        # 3e. always-on invariant monitors (cheap: a handful of integer
-        # compares per lane)
-        self._check_invariants()
+        with GLOBAL_TRACER.span("host/lifecycle", absolute=True):
+            # 3e. always-on invariant monitors (cheap: a handful of
+            # integer compares per lane)
+            self._check_invariants()
 
-        # 4. lifecycle: disconnect GC, then idle eviction
-        self._run_gc(events)
+            # 4. lifecycle: disconnect GC, then idle eviction
+            self._run_gc(events)
         return events
 
     @property
@@ -2252,7 +2250,7 @@ class SessionHost:
                     break
                 # env rows must land THIS tick: retire the fence and
                 # take the dispatch slot the budget was protecting
-                self.device.block_until_ready()
+                self.device.retire_fence()
             env_rows = 0
             for _la, e in env_groups.values():
                 env_rows += len(e)

@@ -239,8 +239,9 @@ class P2PSession:
         rollback batch is still executing on device (the session/advance
         and session/pump spans are the overlap phases — compare their
         total against the backend's tpu/async_fence stalls to see how much
-        of the device time the host actually hid)."""
-        with GLOBAL_TRACER.span("session/advance"):
+        of the device time the host actually hid). The span is absolute:
+        hosted and standalone sessions land in one row."""
+        with GLOBAL_TRACER.span("session/advance", absolute=True):
             return self._advance_frame_impl()
 
     def _advance_frame_impl(self) -> List[Request]:

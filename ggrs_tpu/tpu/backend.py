@@ -81,6 +81,16 @@ def _array_is_ready(arr) -> bool:
     return bool(is_ready()) if callable(is_ready) else True
 
 
+def fence_stall_histogram():
+    """THE ggrs_async_fence_stall_ms instrument, shared by the
+    single-session backend's fence and the host core's."""
+    return GLOBAL_TELEMETRY.registry.histogram(
+        "ggrs_async_fence_stall_ms",
+        "time the host blocked on device work it had dispatched",
+        buckets=LOG2_BUCKETS_MS,
+    )
+
+
 class _ChecksumBatch:
     """One dispatch's worth of device checksums ([W] for a single tick,
     [T, W] for a lazy multi-tick flush — lazy checksum indices are flat
@@ -659,11 +669,7 @@ class TpuRollbackBackend:
         self.plan_cache = plan_cache or DispatchPlanCache()
         # pre-bound telemetry instruments (updated behind enabled checks)
         _reg = GLOBAL_TELEMETRY.registry
-        self._m_fence_stall = _reg.histogram(
-            "ggrs_async_fence_stall_ms",
-            "time the host blocked on the oldest in-flight dispatch",
-            buckets=LOG2_BUCKETS_MS,
-        )
+        self._m_fence_stall = fence_stall_histogram()
         self._m_inflight = _reg.gauge(
             "ggrs_async_inflight", "dispatches currently inside the async fence"
         )
@@ -955,7 +961,6 @@ class TpuRollbackBackend:
         if not self.async_dispatch:
             return
         self._inflight.append(handle)
-        GLOBAL_TRACER.mark("tpu/async_dispatch", absolute=True)
         tel = GLOBAL_TELEMETRY
         if tel.enabled:
             self._m_inflight.set(len(self._inflight))
@@ -1961,6 +1966,7 @@ class MultiSessionDeviceCore:
             "ggrs_host_megabatch_occupancy",
             "live rows / padded bucket size of the last megabatch",
         )
+        self._m_fence_stall = fence_stall_histogram().labels()
 
     @classmethod
     def create(cls, game, max_prediction: int, num_players: int,
@@ -2380,8 +2386,16 @@ class MultiSessionDeviceCore:
         self.inflight_rows += n_rows
         while len(self._inflight) > self.async_inflight:
             oldest, rows = self._inflight.popleft()
-            jax.block_until_ready(oldest)
+            self._fence_wait(oldest)
             self.inflight_rows -= rows
+
+    def _fence_wait(self, handle) -> None:
+        """Block on device work this core dispatched: the in-flight
+        window's oldest entry, or all of it (retire_fence). Timed as a
+        fence stall: span tpu/async_fence and ggrs_async_fence_stall_ms."""
+        feed = self._m_fence_stall if GLOBAL_TELEMETRY.enabled else None
+        with GLOBAL_TRACER.span("tpu/async_fence", absolute=True, feed=feed):
+            jax.block_until_ready(handle)
 
     def poll_retired(self) -> int:
         """Drop already-retired megabatches from the fence without
@@ -3231,6 +3245,13 @@ class MultiSessionDeviceCore:
         jax.block_until_ready(self.states)
         self._inflight.clear()
         self.inflight_rows = 0
+
+    def retire_fence(self) -> None:
+        """block_until_ready for the tick path: the same retirement of
+        every in-flight dispatch, with the wait timed as a fence stall."""
+        self.drive_mailbox()
+        self._fence_wait(self.states)
+        self.block_until_ready()  # nothing left to wait on: retires entries
 
     # ------------------------------------------------------------------
     # durable checkpoint (graceful drain rides this)

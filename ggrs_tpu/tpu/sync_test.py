@@ -28,6 +28,7 @@ import numpy as np
 
 from ..errors import MismatchedChecksum
 from ..types import InputStatus
+from ..utils.tracing import GLOBAL_TRACER
 
 
 def _pick_backend(game, check_distance: int, mesh) -> str:
@@ -311,7 +312,43 @@ class TpuSyncTestSession:
 
         raw_inputs: u8[T, P, input_size] — the inputs submitted at each tick;
         input delay shifts which frame actually plays them.
+
+        Spans (host time only; none reads a device value): synctest/advance
+        around the call, synctest/stage around the delay shift and the
+        inputs' transfer, synctest/dispatch around the batch program's
+        enqueue (which blocks only when the device queue is full).
         """
+        with GLOBAL_TRACER.span("synctest/advance", absolute=True):
+            with GLOBAL_TRACER.span("synctest/stage", absolute=True):
+                inputs = self._stage_inputs(raw_inputs)
+            if self._core is not None and getattr(
+                self._core, "self_jitting", False
+            ):
+                # the reduce-injection core picks its boot/steady program
+                # from a HOST frame counter: a drift from the carry's frame
+                # (core reused with a fresh carry, restored checkpoint
+                # without reset()) would select the steady program for a
+                # boot-phase carry and roll a reduction table whose base
+                # was never pinned — wrong checksums, no error. Trip here.
+                assert self._core.frames_seen == self.current_frame, (
+                    f"core program-selection counter "
+                    f"({self._core.frames_seen}) out of sync with the "
+                    f"session frame ({self.current_frame}); call "
+                    "core.reset(start_frame) when installing a new carry"
+                )
+            with GLOBAL_TRACER.span("synctest/dispatch", absolute=True):
+                self.carry = self._batch_fn(self.carry, inputs)
+            t = raw_inputs.shape[0]
+            self.current_frame += t
+            self._ticks_since_flush += t
+            if (
+                self.flush_interval is not None
+                and self._ticks_since_flush >= self.flush_interval
+            ):
+                self.check()
+
+    def _stage_inputs(self, raw_inputs: np.ndarray):
+        """The device array of the inputs frames [current_frame, +T) play."""
         t = raw_inputs.shape[0]
         start = self.current_frame
         if self.input_delay:
@@ -331,31 +368,16 @@ class TpuSyncTestSession:
             )
         else:
             eff = np.asarray(raw_inputs, dtype=np.uint8)
-        if self._core is not None and getattr(self._core, "self_jitting", False):
-            # the reduce-injection core picks its boot/steady program from
-            # a HOST frame counter: a drift from the carry's frame (core
-            # reused with a fresh carry, restored checkpoint without
-            # reset()) would select the steady program for a boot-phase
-            # carry and roll a reduction table whose base was never
-            # pinned — wrong checksums, no error. Trip here instead.
-            assert self._core.frames_seen == self.current_frame, (
-                f"core program-selection counter ({self._core.frames_seen}) "
-                f"out of sync with the session frame ({self.current_frame}); "
-                "call core.reset(start_frame) when installing a new carry"
-            )
-        self.carry = self._batch_fn(self.carry, jnp.asarray(eff))
-        self.current_frame += t
-        self._ticks_since_flush += t
-        if (
-            self.flush_interval is not None
-            and self._ticks_since_flush >= self.flush_interval
-        ):
-            self.check()
+        return jnp.asarray(eff)
 
     def check(self) -> None:
-        """Fetch the device verdict; raises MismatchedChecksum on divergence."""
+        """Fetch the device verdict; raises MismatchedChecksum on divergence.
+        Span synctest/check: the wait for the batches still queued, plus
+        the verdict's transfer."""
         self._ticks_since_flush = 0
-        if bool(self.carry["mismatch"]):
+        with GLOBAL_TRACER.span("synctest/check", absolute=True):
+            mismatch = bool(self.carry["mismatch"])
+        if mismatch:
             raise MismatchedChecksum(int(self.carry["mismatch_frame"]))
 
     def state_numpy(self):

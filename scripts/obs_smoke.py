@@ -136,11 +136,19 @@ def main():
         "", {}
     ).get("count", 0) == 0:
         fail("no rollbacks recorded — latency harness broken")
-    if not snap["tracer"]:
-        fail("tracer stats did not fold into the snapshot")
+    spans = snap["metrics"].get("ggrs_span_ms", {}).get("values", {})
+    if spans.get("session/advance", {}).get("count", 0) == 0:
+        fail("tracer spans did not reach the registry (ggrs_span_ms)")
+    if snap["tracer"].get("session/advance", {}).get("count") != (
+        spans["session/advance"]["count"]
+    ):
+        fail("the snapshot's tracer section disagrees with ggrs_span_ms")
 
-    # 2. prometheus export parses
-    n_lines = validate_prometheus(GLOBAL_TELEMETRY.prometheus())
+    # 2. prometheus export parses, spans as an ordinary histogram
+    prom = GLOBAL_TELEMETRY.prometheus()
+    n_lines = validate_prometheus(prom)
+    if 'ggrs_span_ms_count{span="session/advance"}' not in prom:
+        fail("ggrs_span_ms missing from the prometheus export")
 
     # 3. desync forensics bundle landed and is diagnosable
     dumps = sorted(os.listdir(dump_dir))

@@ -275,14 +275,20 @@ def test_spectator_session_telemetry(telemetry):
 def test_tracer_stats_fold_into_snapshot():
     from ggrs_tpu.utils.tracing import Tracer
 
-    t = Tracer(enabled=True)
+    tel = Telemetry(enabled=True)
+    t = Tracer(enabled=True, registry=tel.registry)
     with t.span("tick"):
         pass
-    tel = Telemetry(enabled=True)
     snap = tel.snapshot(tracer=t)
     assert snap["tracer"]["tick"]["count"] == 1
-    text = tel.prometheus(tracer=t)
-    assert 'ggrs_tracer_span_count{span="tick"} 1' in text
+    # the span table is an ordinary registry histogram in both exporters
+    assert snap["metrics"]["ggrs_span_ms"]["values"]["tick"]["count"] == 1
+    assert snap["tracer"]["tick"]["total_ms"] == (
+        snap["metrics"]["ggrs_span_ms"]["values"]["tick"]["sum"]
+    )
+    text = tel.prometheus()
+    assert 'ggrs_span_ms_count{span="tick"} 1' in text
+    assert "ggrs_tracer_span" not in text
 
 
 # ---------------------------------------------------------------------------
