@@ -17,10 +17,11 @@ per-entity weighted modular sum. Adapters declare `tileable = True`
 whole-batch kernel or the XLA scan). Checksums are emitted as PARTIAL
 per-tile sums accumulated across grid steps in an SMEM revisit buffer
 (uint32 wraparound sums are order-invariant, so the total is bit-identical
-to the unsharded checksum); the first-seen history compare — a few hundred
-scalar ops — moves to a jnp post-pass over the per-save totals, carrying
-the same h_tag/h_hi/h_lo/mismatch state as TpuSyncTestSession's carry, so
-the tiled core is a drop-in `backend="pallas-tiled"`.
+to the unsharded checksum); the first-seen history compare moves to a
+loop-free jnp post-pass over the per-save totals (`first_seen_verdict`:
+running maxima over the T*d events, no per-event loop), carrying the same
+h_tag/h_hi/h_lo/mismatch state as TpuSyncTestSession's carry, so the tiled
+core is a drop-in `backend="pallas-tiled"`.
 
 Save-event layout the post-pass decodes (mirroring TpuSyncTestSession._tick
 for tick frame c = c0 + t):
@@ -49,6 +50,61 @@ from .pallas_core import (
 )
 
 LANE = 128
+
+
+def first_seen_verdict(hc, frames, valid, hi, lo):
+    """The first-seen history after a stream of save events, loop-free.
+
+    Bit for bit the result of applying TpuSyncTestSession._save_and_check's
+    rule to the events one by one, in order (an invalid event changes
+    nothing): an event whose slot `frame % hist` already holds its frame's
+    tag compares its checksum with the stored one, any other valid event
+    stores its tag and checksum. `hc` holds h_tag/h_hi/h_lo [hist] and the
+    mismatch/mismatch_frame scalars; frames/valid/hi/lo are [E].
+
+    What an event meets in its slot is what the last earlier event there
+    left: the tag of the last valid one, the checksum of the last one that
+    stored (or the carry's, if none). An exclusive running max of event
+    indices per slot finds both, so the cost is O(E * hist) with no loop.
+    """
+    hist = hc["h_tag"].shape[0]
+    idx = jnp.arange(frames.shape[0], dtype=jnp.int32)
+    slot = frames % hist
+    in_slot = slot[:, None] == jnp.arange(hist, dtype=jnp.int32)[None, :]
+
+    def last_marked(mask):
+        """Per event, the index of the last EARLIER event in its slot with
+        `mask` set; per slot, the last such event overall (-1: none)."""
+        marks = jnp.where(in_slot & mask[:, None], idx[:, None], -1)
+        upto = jax.lax.cummax(marks, axis=0)
+        before = jnp.concatenate(
+            [jnp.full((1, hist), -1, jnp.int32), upto[:-1]]
+        )
+        return jnp.max(jnp.where(in_slot, before, -1), axis=1), upto[-1]
+
+    def pick(values, at, fallback):
+        return jnp.where(at >= 0, values[jnp.maximum(at, 0)], fallback)
+
+    tagged_before, tagged_last = last_marked(valid)
+    seen = pick(frames, tagged_before, hc["h_tag"][slot]) == frames
+    fresh = valid & ~seen
+    stored_before, stored_last = last_marked(fresh)
+    differs = valid & seen & (
+        (pick(hi, stored_before, hc["h_hi"][slot]) != hi)
+        | (pick(lo, stored_before, hc["h_lo"][slot]) != lo)
+    )
+    any_differs = jnp.any(differs)
+    return {
+        "h_tag": pick(frames, tagged_last, hc["h_tag"]),
+        "h_hi": pick(hi, stored_last, hc["h_hi"]),
+        "h_lo": pick(lo, stored_last, hc["h_lo"]),
+        "mismatch": hc["mismatch"] | any_differs,
+        "mismatch_frame": jnp.where(
+            any_differs & ~hc["mismatch"],
+            frames[jnp.argmax(differs)],
+            hc["mismatch_frame"],
+        ),
+    }
 
 
 class PallasTiledSyncTestCore:
@@ -109,7 +165,6 @@ class PallasTiledSyncTestCore:
         self.input_size = game.input_size
         self.d = check_distance
         self.ring_len = check_distance + 2
-        self.hist_len = check_distance + 2
         self.n_rows = self.n // LANE
         self.interpret = interpret
         n_planes = len(self.adapter.planes)
@@ -466,10 +521,10 @@ class PallasTiledSyncTestCore:
     # -- post-pass: first-seen history over the per-save totals ----------
 
     def _verdict(self, carry, parts_hi, parts_lo, c0, t_ticks):
-        """jnp scan over the T*d save events (a few hundred scalars),
-        carrying the session's h_tag/h_hi/h_lo/mismatch exactly like
-        TpuSyncTestSession._save_and_check."""
-        d, hist = self.d, self.hist_len
+        """Decode the kernel's T*d save events (module docstring layout)
+        and fold them into the session's h_tag/h_hi/h_lo/mismatch exactly
+        like TpuSyncTestSession._save_and_check (`first_seen_verdict`)."""
+        d = self.d
         t_idx = jnp.arange(t_ticks, dtype=jnp.int32)[:, None]
         j_idx = jnp.arange(d, dtype=jnp.int32)[None, :]
         c = c0 + t_idx
@@ -482,43 +537,13 @@ class PallasTiledSyncTestCore:
         flat_frames = frames.reshape(-1)
         ev_hi = parts_hi.reshape(-1) + flat_frames * self._cs_frame_weight
         ev_lo = parts_lo.reshape(-1) + flat_frames
-        ev = (
+        hc = first_seen_verdict(
+            carry,
             flat_frames,
             valid.reshape(-1),
             jax.lax.bitcast_convert_type(ev_hi, jnp.uint32),
             jax.lax.bitcast_convert_type(ev_lo, jnp.uint32),
         )
-
-        def body(hc, e):
-            frame, ok, hi, lo = e
-            h = frame % hist
-            seen = hc["h_tag"][h] == frame
-            differs = ok & seen & ((hc["h_hi"][h] != hi) | (hc["h_lo"][h] != lo))
-            first = differs & ~hc["mismatch"]
-            return {
-                "h_tag": hc["h_tag"].at[h].set(
-                    jnp.where(ok, frame, hc["h_tag"][h])
-                ),
-                "h_hi": hc["h_hi"].at[h].set(
-                    jnp.where(ok & ~seen, hi, hc["h_hi"][h])
-                ),
-                "h_lo": hc["h_lo"].at[h].set(
-                    jnp.where(ok & ~seen, lo, hc["h_lo"][h])
-                ),
-                "mismatch": hc["mismatch"] | differs,
-                "mismatch_frame": jnp.where(
-                    first, frame, hc["mismatch_frame"]
-                ),
-            }, None
-
-        hc = {
-            "h_tag": carry["h_tag"],
-            "h_hi": carry["h_hi"],
-            "h_lo": carry["h_lo"],
-            "mismatch": carry["mismatch"],
-            "mismatch_frame": carry["mismatch_frame"],
-        }
-        hc, _ = jax.lax.scan(body, hc, ev)
         hc["frame"] = c0 + t_ticks
         return hc
 

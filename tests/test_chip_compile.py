@@ -23,14 +23,13 @@ T_BATCH = 60  # fused ticks per dispatch (chip_smoke phase A, bench)
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
-    from jax.sharding import SingleDeviceSharding
 
     try:
-        topo = topologies.get_topology_desc(
+        desc = topologies.get_topology_desc(
             platform="tpu", topology_name="v5e:2x2"
         )
     except Exception as e:
@@ -40,8 +39,15 @@ def one_chip():
     enabled = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield desc
     jax.config.update("jax_enable_compilation_cache", enabled)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(topo.devices[0])
 
 
 def _struct(tree, sharding):
@@ -91,6 +97,34 @@ def test_tiled_synctest_kernel_compiles(one_chip):
     core = PallasTiledSyncTestCore(game, 2, 8)
     _compile_kernel(core.batch, _synctest_carry(game, 8, one_chip),
                     _inputs(one_chip))
+
+
+def test_sharded_tiled_synctest_compiles_loop_free(topo):
+    """The four-chip SyncTest batch (the synctest_mesh4 benchmark cell's
+    shape): the kernel and its psum, and a first-seen verdict with no
+    device loop around them."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    from ggrs_tpu.tpu.pallas_tiled import ShardedPallasTiledCore
+
+    mesh = Mesh(np.asarray(topo.devices).reshape(2, 2), ("beam", "entity"))
+    game, d = ExGame(2, 13056), 16
+    core = ShardedPallasTiledCore(game, 2, d, mesh)
+    shapes = _synctest_carry(game, d, None)
+    carry = jax.tree.map(
+        lambda x, spec: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=NamedSharding(mesh, spec)
+        ),
+        shapes,
+        core._carry_specs(shapes),
+    )
+    inputs = jax.ShapeDtypeStruct(
+        (T_BATCH, 2, 1), jnp.uint8,
+        sharding=NamedSharding(mesh, PartitionSpec()),
+    )
+    text = _compile_kernel(core.batch, carry, inputs).as_text()
+    assert " while(" not in text
 
 
 @pytest.mark.parametrize("rows", [1, 8])
