@@ -525,6 +525,11 @@ class SessionHost:
             "+ forensics bundle each)",
             ("invariant",),
         )
+        self._m_spectator_starved = _reg.counter(
+            "ggrs_spectator_starved_total",
+            "spectator lane ticks that could not advance: the host "
+            "peer's input for the next frame had not arrived",
+        )
         # fleet-wide batched wire pump + host-tax attribution (the pump
         # phase's own child is observed inside WirePump.pump; the shared
         # instrument is defined once, in network/pump.py)
@@ -1064,6 +1069,8 @@ class SessionHost:
                 except PredictionThreshold:
                     # spectator whose host input hasn't arrived: benign
                     lane.throttled_ticks += 1
+                    if tel.enabled and lane.kind == "spectator":
+                        self._m_spectator_starved.inc()
                     continue
                 except GGRSError as exc:
                     lane.last_error = type(exc).__name__

@@ -54,6 +54,13 @@ from ..types import (
 
 from .builder import MAX_EVENT_QUEUE_SIZE
 
+# the host's fan-out: one per confirmed input sent to a running spectator
+# endpoint (recorded only while telemetry is on)
+_m_spectator_sends = GLOBAL_TELEMETRY.registry.counter(
+    "ggrs_spectator_sends_total",
+    "confirmed inputs a P2P host sent to its running spectator endpoints",
+)
+
 RECOMMENDATION_INTERVAL = 60
 MIN_RECOMMENDATION = 3
 
@@ -729,24 +736,29 @@ class P2PSession:
             )
 
     def _send_confirmed_inputs_to_spectators(self, confirmed_frame: Frame) -> None:
-        """(src/sessions/p2p_session.rs:676-703)"""
+        """(src/sessions/p2p_session.rs:676-703). Span absolute: it runs
+        inside session/advance, and its row reads the same either way."""
         if self.num_spectators() == 0:
             return
-        while self.next_spectator_frame <= confirmed_frame:
-            inputs = self.sync_layer.confirmed_inputs(
-                self.next_spectator_frame, self.local_connect_status
-            )
-            assert len(inputs) == self.num_players
-            input_map = {}
-            for handle, inp in enumerate(inputs):
-                assert inp.frame in (NULL_FRAME, self.next_spectator_frame)
-                # disconnected dummies must still carry the right frame so the
-                # endpoint-level frame stamp stays consistent
-                input_map[handle] = PlayerInput(self.next_spectator_frame, inp.buf)
-            for endpoint in self.player_reg.spectators.values():
-                if endpoint.is_running():
-                    endpoint.send_input(input_map, self.local_connect_status)
-            self.next_spectator_frame += 1
+        counting = GLOBAL_TELEMETRY.enabled
+        with GLOBAL_TRACER.span("session/spectator_send", absolute=True):
+            while self.next_spectator_frame <= confirmed_frame:
+                inputs = self.sync_layer.confirmed_inputs(
+                    self.next_spectator_frame, self.local_connect_status
+                )
+                assert len(inputs) == self.num_players
+                input_map = {}
+                for handle, inp in enumerate(inputs):
+                    assert inp.frame in (NULL_FRAME, self.next_spectator_frame)
+                    # disconnected dummies must still carry the right frame so the
+                    # endpoint-level frame stamp stays consistent
+                    input_map[handle] = PlayerInput(self.next_spectator_frame, inp.buf)
+                for endpoint in self.player_reg.spectators.values():
+                    if endpoint.is_running():
+                        endpoint.send_input(input_map, self.local_connect_status)
+                        if counting:
+                            _m_spectator_sends.inc()
+                self.next_spectator_frame += 1
 
     def _update_player_disconnects(self) -> None:
         """Cross-peer disconnect reconciliation

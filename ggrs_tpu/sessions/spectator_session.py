@@ -41,10 +41,27 @@ from ..types import (
     Synchronized,
     Synchronizing,
 )
+from ..utils.tracing import GLOBAL_TRACER
 
 from .builder import MAX_EVENT_QUEUE_SIZE, SPECTATOR_BUFFER_SIZE
 
 NORMAL_SPEED = 1
+
+# frames spectators advanced, split by whether the catch-up rule fired,
+# and how many received frames a viewer still trails after each advance
+# (recorded only while telemetry is on)
+_m_frames = GLOBAL_TELEMETRY.registry.counter(
+    "ggrs_spectator_frames_total",
+    "frames spectators advanced, by speed (normal | catchup)",
+    ("speed",),
+)
+_m_frames_normal = _m_frames.labels("normal")
+_m_frames_catchup = _m_frames.labels("catchup")
+_m_frames_behind = GLOBAL_TELEMETRY.registry.histogram(
+    "ggrs_spectator_frames_behind",
+    "frames a spectator is behind the host's inputs it has received, "
+    "after each successful advance",
+)
 
 
 class SpectatorSession:
@@ -140,7 +157,13 @@ class SpectatorSession:
         return out
 
     def advance_frame(self) -> List[Request]:
-        """(src/sessions/p2p_spectator_session.rs:109-138)"""
+        """(src/sessions/p2p_spectator_session.rs:109-138). The span is
+        absolute, as P2PSession's session/advance: hosted and standalone
+        spectators land in one row."""
+        with GLOBAL_TRACER.span("spectator/advance", absolute=True):
+            return self._advance_frame_impl()
+
+    def _advance_frame_impl(self) -> List[Request]:
         # hosted sessions skip the internal pump (see P2PSession's twin):
         # the SessionHost already drained this tick
         if self._host is None:
@@ -149,17 +172,18 @@ class SpectatorSession:
             raise NotSynchronized()
 
         requests: List[Request] = []
-        frames_to_advance = (
-            self.catchup_speed
-            if self.frames_behind_host() > self.max_frames_behind
-            else NORMAL_SPEED
-        )
+        catchup = self.frames_behind_host() > self.max_frames_behind
+        frames_to_advance = self.catchup_speed if catchup else NORMAL_SPEED
         for _ in range(frames_to_advance):
             frame_to_grab = self.current_frame + 1
             synced_inputs = self._inputs_at_frame(frame_to_grab)
             requests.append(AdvanceFrame(inputs=synced_inputs))
             # only advance if grabbing the inputs succeeded
             self.current_frame += 1
+        if GLOBAL_TELEMETRY.enabled:
+            frames = _m_frames_catchup if catchup else _m_frames_normal
+            frames.inc(frames_to_advance)
+            _m_frames_behind.observe(self.frames_behind_host())
         return requests
 
     def poll_remote_clients(self) -> None:
