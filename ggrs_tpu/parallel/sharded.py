@@ -42,6 +42,18 @@ def ring_specs(ring):
     )
 
 
+def synctest_carry_specs(carry):
+    """PartitionSpec tree for a fused SyncTest carry: the world and the
+    snapshot ring as `state_specs` / `ring_specs`, every other leaf (input
+    ring, checksum history, verdict, frame) replicated. Where the session
+    places its carry and what the sharded tiled kernel's shard_map takes
+    and returns, so the two cannot drift."""
+    specs = {k: P() for k in carry}
+    specs["state"] = state_specs(carry["state"])
+    specs["ring"] = ring_specs(carry["ring"])
+    return specs
+
+
 def shard_state(state, mesh: Mesh):
     """Place a game-state pytree on the mesh per `state_specs` (every
     consumer — ResimCore, TpuSyncTestSession, the beam rollout — must route

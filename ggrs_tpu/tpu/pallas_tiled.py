@@ -806,21 +806,9 @@ class ShardedPallasTiledCore:
         return self._frames_seen
 
     def _carry_specs(self, carry):
-        from jax.sharding import PartitionSpec as P
+        from ..parallel.sharded import synctest_carry_specs
 
-        from ..parallel.sharded import ring_specs, state_specs
-
-        return {
-            "state": state_specs(carry["state"]),
-            "ring": ring_specs(carry["ring"]),
-            "input_ring": P(),
-            "h_tag": P(),
-            "h_hi": P(),
-            "h_lo": P(),
-            "mismatch": P(),
-            "mismatch_frame": P(),
-            "frame": P(),
-        }
+        return synctest_carry_specs(carry)
 
     def batch(self, carry: Dict[str, Any], inputs) -> Dict[str, Any]:
         if self.self_jitting:

@@ -2,10 +2,21 @@
 
 The host SyncTestSession + TpuRollbackBackend pair already fuses each tick
 into one dispatch, but still returns to Python every frame and resolves
-checksums. This session goes further: T ticks per dispatch via `lax.scan`,
-with the snapshot ring, the input history, the checksum history and the
-mismatch verdict all living on device. Only (a) the input batch goes down
-and (b) a single mismatch flag comes back per batch.
+checksums. This session goes further: T ticks per batch program via
+`lax.scan` (or one pallas kernel), with the snapshot ring, the input
+history, the checksum history and the mismatch verdict all living on
+device. Only (a) the inputs go down and (b) a single mismatch flag comes
+back, when `check()` asks for it.
+
+Consecutive `advance_frames` calls coalesce: each call stages its inputs
+on the host, and once `max(1, COALESCE_FRAMES // T)` batches of the same
+length are pending they go down in one transfer and run as one device
+program that applies the batch program to each in turn. Anything that
+observes the carry (`check()`, `state_numpy()`, `block_until_ready()`,
+`save()`, reading or assigning `carry`) first dispatches what is pending,
+one single-batch program per batch, so a caller that reads the carry after
+every call gets one dispatch per call. The frames, their order and the
+verdict are the same either way; only the number of host calls changes.
 
 Semantics mirror src/sessions/sync_test_session.rs:85-146: each tick, once
 past `check_distance`, load the snapshot `check_distance` frames back,
@@ -26,9 +37,26 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..errors import MismatchedChecksum
+from ..errors import InvalidRequest, MismatchedChecksum
+from ..obs import GLOBAL_TELEMETRY
 from ..types import InputStatus
 from ..utils.tracing import GLOBAL_TRACER
+
+# frames one coalesced dispatch carries: 8 batches of 60. A call of this
+# many frames or more runs alone, as every call did before coalescing.
+COALESCE_FRAMES = 480
+
+# one per advance_frames call, one per device program the calls ran as
+# (recorded only while telemetry is on)
+_m_batches = GLOBAL_TELEMETRY.registry.counter(
+    "ggrs_synctest_batches_total",
+    "advance_frames batches fused SyncTest sessions staged",
+)
+_m_dispatches = GLOBAL_TELEMETRY.registry.counter(
+    "ggrs_synctest_dispatches_total",
+    "device programs fused SyncTest sessions dispatched: one per "
+    "coalesced group, one per batch flushed before its group filled",
+)
 
 
 def _pick_backend(game, check_distance: int, mesh) -> str:
@@ -73,6 +101,21 @@ def _pick_backend(game, check_distance: int, mesh) -> str:
     ) and game.num_entities % (mesh.shape["entity"] * 128) == 0:
         return "pallas-tiled"
     return "xla"
+
+
+def group_program(batch):
+    """The program one coalesced dispatch runs: a scan of the jitted
+    single-batch program `batch(carry, inputs)` over the [T, ...] batches
+    of a [K, T, ...] block. The scan keeps one copy of the kernel in the
+    executable; unrolled, the sharded tiled program holds K copies and
+    loads from the compile cache seconds slower."""
+
+    def group(carry, block):
+        return jax.lax.scan(
+            lambda c, inputs: (batch(c, inputs), None), carry, block
+        )[0]
+
+    return group
 
 
 class TpuSyncTestSession:
@@ -147,13 +190,28 @@ class TpuSyncTestSession:
         self.ring_len = d + 2
         self.hist_len = d + 2
 
-        if _defer_carry:
+        # the batch programs' own input sharding: inputs are placed there
+        # once, so no call reshards them
+        if mesh is None:
+            self._in_sharding = jax.sharding.SingleDeviceSharding(
+                jax.devices()[0]
+            )
+        else:
+            from jax.sharding import NamedSharding, PartitionSpec
+
+            self._in_sharding = NamedSharding(mesh, PartitionSpec())
+        # staged batches not yet dispatched: the first `_pending` rows of
+        # `_group` ([K, T, P, input_size]), T = `_group_t`
+        self._group = None
+        self._group_t = 0
+        self._pending = 0
+        self._primed = set()  # batch lengths whose group program is compiled
+        self._carry = None
+        if not _defer_carry:
             # restore() installs a checkpointed carry right after
             # construction: building the initial one (a full init_state
             # plus ring_len world-sized zero buffers) would be a
             # multi-hundred-MB transient at large-world scale
-            self.carry = None
-        else:
             self._build_initial_carry()
         self._core = None  # kernel core owning host-side program selection
         if backend == "xla":
@@ -197,28 +255,52 @@ class TpuSyncTestSession:
                 interpret=backend == "pallas-interpret",
             )
             self._batch_fn = jax.jit(core.batch, donate_argnums=(0,))
+            self._core = core
+        # the sharded reduce-injection core picks its boot or steady
+        # program per call from a host frame counter: it dispatches every
+        # call alone
+        self._coalesce = not getattr(self._core, "self_jitting", False)
+        self._group_fn = (
+            jax.jit(group_program(self._batch_fn), donate_argnums=(0,))
+            if self._coalesce
+            else None
+        )
         self._raw_inputs: list = []  # host-side delay shift buffer
         self._ticks_since_flush = 0
         self.current_frame = 0
 
+    @property
+    def carry(self):
+        """The device carry, with every staged batch dispatched first."""
+        self._flush()
+        return self._carry
+
+    @carry.setter
+    def carry(self, carry) -> None:
+        """Install a carry (after dispatching what is staged on the old
+        one), placed on the batch programs' own shardings."""
+        self._flush()
+        self._carry = None if carry is None else self._place(carry)
+
+    def _place(self, carry):
+        if self.mesh is None:
+            return jax.device_put(carry, self._in_sharding)
+        from jax.sharding import NamedSharding
+
+        from ..parallel.sharded import synctest_carry_specs
+
+        return jax.device_put(carry, jax.tree.map(
+            lambda x, spec: NamedSharding(self.mesh, spec),
+            carry, synctest_carry_specs(carry),
+        ))
+
     def _build_initial_carry(self) -> None:
-        game, mesh = self.game, self.mesh
+        game = self.game
         num_players, d = self.num_players, self.check_distance
         state = game.init_state()
-        if mesh is not None:
-            from ..parallel.sharded import shard_ring, shard_state
-
-            state = shard_state(state, mesh)
-            zeros = lambda extra: shard_ring(
-                jax.tree.map(
-                    lambda x: jnp.zeros((extra,) + x.shape, x.dtype), state
-                ),
-                mesh,
-            )
-        else:
-            zeros = lambda extra: jax.tree.map(
-                lambda x: jnp.zeros((extra,) + x.shape, x.dtype), state
-            )
+        zeros = lambda extra: jax.tree.map(
+            lambda x: jnp.zeros((extra,) + x.shape, x.dtype), state
+        )
         self.carry = {
             "state": state,
             "ring": zeros(self.ring_len),
@@ -308,22 +390,38 @@ class TpuSyncTestSession:
     # ------------------------------------------------------------------
 
     def advance_frames(self, raw_inputs: np.ndarray) -> None:
-        """Advance T frames in ONE device dispatch.
+        """Advance T frames: stage them, and dispatch once a group is full.
 
         raw_inputs: u8[T, P, input_size] — the inputs submitted at each tick;
         input delay shifts which frame actually plays them.
 
+        The effective inputs are copied into a host buffer. Once
+        K = max(1, COALESCE_FRAMES // T) batches of this length are staged
+        they go down in one transfer and run as one device program; a
+        batch of another length first dispatches the staged ones. So
+        T >= COALESCE_FRAMES dispatches every call, as does the sharded
+        reduce-injection core. The first batch of a length compiles its
+        group program, so the compile lands in the caller's warm-up.
+
         Spans (host time only; none reads a device value): synctest/advance
         around the call, synctest/stage around the delay shift and the
-        inputs' transfer, synctest/dispatch around the batch program's
-        enqueue (which blocks only when the device queue is full).
+        copy into the host buffer, synctest/dispatch around each device
+        program's input transfer and enqueue (which blocks only when the
+        device queue is full), inside whichever span dispatched it.
         """
+        raw = np.asarray(raw_inputs, dtype=np.uint8)
+        want = (self.num_players, self.game.input_size)
+        if raw.ndim != 3 or raw.shape[0] < 1 or raw.shape[1:] != want:
+            raise InvalidRequest(
+                f"advance_frames takes u8[T >= 1, {want[0]}, {want[1]}] "
+                f"inputs, got shape {raw.shape}"
+            )
+        t = raw.shape[0]
         with GLOBAL_TRACER.span("synctest/advance", absolute=True):
-            with GLOBAL_TRACER.span("synctest/stage", absolute=True):
-                inputs = self._stage_inputs(raw_inputs)
-            if self._core is not None and getattr(
-                self._core, "self_jitting", False
-            ):
+            if t != self._group_t:
+                self._flush()
+                self._open_group(t)
+            if not self._coalesce:
                 # the reduce-injection core picks its boot/steady program
                 # from a HOST frame counter: a drift from the carry's frame
                 # (core reused with a fresh carry, restored checkpoint
@@ -336,49 +434,89 @@ class TpuSyncTestSession:
                     f"session frame ({self.current_frame}); call "
                     "core.reset(start_frame) when installing a new carry"
                 )
-            with GLOBAL_TRACER.span("synctest/dispatch", absolute=True):
-                self.carry = self._batch_fn(self.carry, inputs)
-            t = raw_inputs.shape[0]
+            with GLOBAL_TRACER.span("synctest/stage", absolute=True):
+                self._group[self._pending] = self._effective_inputs(raw)
+                self._pending += 1
+            if GLOBAL_TELEMETRY.enabled:
+                _m_batches.inc()
             self.current_frame += t
             self._ticks_since_flush += t
+            if self._pending == len(self._group):
+                self._flush()
             if (
                 self.flush_interval is not None
                 and self._ticks_since_flush >= self.flush_interval
             ):
                 self.check()
 
-    def _stage_inputs(self, raw_inputs: np.ndarray):
-        """The device array of the inputs frames [current_frame, +T) play."""
-        t = raw_inputs.shape[0]
-        start = self.current_frame
-        if self.input_delay:
-            # frame f plays the input submitted at f-delay; the first `delay`
-            # frames play the blank input (queue-head replication of the
-            # pristine slot, input_queue.rs:207-239). The raw history is tiny
-            # (bytes/frame), keep it whole.
-            self._raw_inputs.extend(np.asarray(raw_inputs, dtype=np.uint8))
-            blank = np.zeros_like(self._raw_inputs[0])
-            eff = np.stack(
-                [
-                    self._raw_inputs[f - self.input_delay]
-                    if f >= self.input_delay
-                    else blank
-                    for f in range(start, start + t)
-                ]
+    def _open_group(self, t: int) -> None:
+        """A host buffer for K batches of length t; the first time t
+        arrives with K > 1, compile the group program for it from the
+        carry's and the block's shapes and shardings."""
+        k = max(1, COALESCE_FRAMES // t) if self._coalesce else 1
+        self._group = np.zeros(
+            (k, t, self.num_players, self.game.input_size), np.uint8
+        )
+        self._group_t = t
+        if k > 1 and t not in self._primed and self._carry is not None:
+            block = jax.ShapeDtypeStruct(
+                self._group.shape, np.uint8, sharding=self._in_sharding
             )
+            self._group_fn.lower(self._carry, block).compile()
+            self._primed.add(t)
+
+    def _flush(self) -> None:
+        """Dispatch every staged batch: a full group as one program, a
+        partial one through the single-batch program, batch by batch."""
+        n = self._pending
+        if n == 0:
+            return
+        group = self._group
+        self._pending = 0
+        # a fresh buffer: a transfer may alias the host memory it reads
+        self._group = np.zeros_like(group)
+        if n == len(group) and n > 1:
+            calls = [(self._group_fn, group)]
         else:
-            eff = np.asarray(raw_inputs, dtype=np.uint8)
-        return jnp.asarray(eff)
+            calls = [(self._batch_fn, group[i]) for i in range(n)]
+        for fn, inputs in calls:
+            with GLOBAL_TRACER.span("synctest/dispatch", absolute=True):
+                inputs = jax.device_put(inputs, self._in_sharding)
+                self._carry = fn(self._carry, inputs)
+            if GLOBAL_TELEMETRY.enabled:
+                _m_dispatches.inc()
+
+    def _effective_inputs(self, raw: np.ndarray) -> np.ndarray:
+        """The inputs frames [current_frame, +T) play."""
+        if not self.input_delay:
+            return raw
+        t = raw.shape[0]
+        start = self.current_frame
+        # frame f plays the input submitted at f-delay; the first `delay`
+        # frames play the blank input (queue-head replication of the
+        # pristine slot, input_queue.rs:207-239). The raw history is tiny
+        # (bytes/frame), keep it whole.
+        self._raw_inputs.extend(raw)
+        blank = np.zeros_like(self._raw_inputs[0])
+        return np.stack(
+            [
+                self._raw_inputs[f - self.input_delay]
+                if f >= self.input_delay
+                else blank
+                for f in range(start, start + t)
+            ]
+        )
 
     def check(self) -> None:
-        """Fetch the device verdict; raises MismatchedChecksum on divergence.
-        Span synctest/check: the wait for the batches still queued, plus
-        the verdict's transfer."""
+        """Dispatch what is staged, then fetch the device verdict; raises
+        MismatchedChecksum on divergence. Span synctest/check: those
+        dispatches, the wait for the batches still queued, and the
+        verdict's transfer."""
         self._ticks_since_flush = 0
         with GLOBAL_TRACER.span("synctest/check", absolute=True):
             mismatch = bool(self.carry["mismatch"])
         if mismatch:
-            raise MismatchedChecksum(int(self.carry["mismatch_frame"]))
+            raise MismatchedChecksum(int(self._carry["mismatch_frame"]))
 
     def state_numpy(self):
         return jax.device_get(self.carry["state"])
@@ -410,8 +548,6 @@ class TpuSyncTestSession:
         """Checkpoints are backend-agnostic (the carry pytree is identical
         across the XLA scan and both pallas kernels), so a run saved under
         one backend can resume under any other."""
-        import jax as _jax
-
         from ..utils.checkpoint import load_device_checkpoint
 
         tree, meta = load_device_checkpoint(path)
@@ -425,7 +561,7 @@ class TpuSyncTestSession:
             backend=backend,
             _defer_carry=True,  # the checkpoint replaces the initial carry
         )
-        sess.carry = _jax.device_put(tree)
+        sess.carry = tree
         sess.current_frame = meta["current_frame"]
         if sess._core is not None and hasattr(sess._core, "reset"):
             # re-arm host-side program selection to the restored carry's

@@ -20,6 +20,7 @@ import pytest
 from ggrs_tpu.models.ex_game import ExGame
 
 T_BATCH = 60  # fused ticks per dispatch (chip_smoke phase A, bench)
+KERNEL = 'custom_call_target="tpu_custom_call"'
 
 
 @pytest.fixture(scope="module")
@@ -82,12 +83,21 @@ def _inputs(one_chip, t=T_BATCH):
 
 
 def test_whole_batch_synctest_kernel_compiles(one_chip):
+    """The synctest_d8 cell's batch, and the coalesced program that runs K
+    such batches per dispatch: a scan over them whose executable holds the
+    kernel once."""
     from ggrs_tpu.tpu.pallas_core import PallasSyncTestCore
+    from ggrs_tpu.tpu.sync_test import COALESCE_FRAMES, group_program
 
     game = ExGame(2, 4096)
     core = PallasSyncTestCore(game, 2, 8)
-    _compile_kernel(core.batch, _synctest_carry(game, 8, one_chip),
-                    _inputs(one_chip))
+    carry = _synctest_carry(game, 8, one_chip)
+    _compile_kernel(core.batch, carry, _inputs(one_chip))
+    k = COALESCE_FRAMES // T_BATCH
+    block = jax.ShapeDtypeStruct((k, T_BATCH, 2, 1), jnp.uint8,
+                                 sharding=one_chip)
+    group = group_program(jax.jit(core.batch))
+    assert _compile_kernel(group, carry, block).as_text().count(KERNEL) == 1
 
 
 def test_tiled_synctest_kernel_compiles(one_chip):
@@ -102,11 +112,14 @@ def test_tiled_synctest_kernel_compiles(one_chip):
 def test_sharded_tiled_synctest_compiles_loop_free(topo):
     """The four-chip SyncTest batch (the synctest_mesh4 benchmark cell's
     shape): the kernel and its psum, and a first-seen verdict with no
-    device loop around them."""
+    device loop around them; and the coalesced program that runs K such
+    batches per dispatch, which scans over them so that its executable
+    holds the kernel once."""
     import numpy as np
     from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
     from ggrs_tpu.tpu.pallas_tiled import ShardedPallasTiledCore
+    from ggrs_tpu.tpu.sync_test import COALESCE_FRAMES, group_program
 
     mesh = Mesh(np.asarray(topo.devices).reshape(2, 2), ("beam", "entity"))
     game, d = ExGame(2, 13056), 16
@@ -125,6 +138,13 @@ def test_sharded_tiled_synctest_compiles_loop_free(topo):
     )
     text = _compile_kernel(core.batch, carry, inputs).as_text()
     assert " while(" not in text
+    k = COALESCE_FRAMES // T_BATCH
+    block = jax.ShapeDtypeStruct(
+        (k, T_BATCH, 2, 1), jnp.uint8,
+        sharding=NamedSharding(mesh, PartitionSpec()),
+    )
+    group = group_program(jax.jit(core.batch))
+    assert _compile_kernel(group, carry, block).as_text().count(KERNEL) == 1
 
 
 @pytest.mark.parametrize("rows", [1, 8])
