@@ -36,7 +36,7 @@ _MUTATORS = {
 # device-core shared state: the async-fence carry, the pooled staging
 # buffers, and the dispatch-plan cache
 CORE_STATE: FrozenSet[str] = frozenset({
-    "_inflight", "inflight_rows",
+    "_inflight", "rows_this_tick",
     "_stage_pool", "_stage_pools", "_stage_flip",
     "_multi_bufs", "_multi_flip", "_multi_active", "_multi_count",
     "_pad_row", "_tick_rows", "_tick_future", "_buffered_last_active",
@@ -69,7 +69,7 @@ class FencePolicy:
 
 # the fence/dispatch entry points per protected module. serve/host.py
 # deliberately has NO allowances: the host must drive the device core
-# through its methods (dispatch/poll_retired/reset_slot/...), never by
+# through its methods (dispatch/begin_tick/reset_slot/...), never by
 # reaching into `self.device.<state>`.
 POLICIES: Dict[str, FencePolicy] = {
     "ggrs_tpu/tpu/backend.py": FencePolicy(
@@ -85,7 +85,7 @@ POLICIES: Dict[str, FencePolicy] = {
             ("TpuRollbackBackend", "block_until_ready"),
             ("MultiSessionDeviceCore", "__init__"),
             ("MultiSessionDeviceCore", "_note_inflight"),
-            ("MultiSessionDeviceCore", "poll_retired"),
+            ("MultiSessionDeviceCore", "begin_tick"),
             ("MultiSessionDeviceCore", "_acquire_stage"),
             ("MultiSessionDeviceCore", "dispatch"),
             # dispatch_rows shares the staged tail; the masked batch

@@ -65,19 +65,19 @@ class DrainStalled(GGRSError):
     """A host flush (graceful drain, migration export, kill-time
     checkpoint) failed to empty the ready queue: some staged rows can
     never dispatch — a wedged fence, a monkeypatched scheduler, or a bug
-    in the budget accounting. Carries the stuck queue depth and the last
-    observed fence state so an operator sees WHAT is wedged, not a bare
-    AssertionError from the guard counter."""
+    in the budget accounting. Carries the stuck queue depth and the rows
+    the last pass dispatched so an operator sees WHAT is wedged, not a
+    bare AssertionError from the guard counter."""
 
     def __init__(self, info: str, *, queue_depth: int = 0,
-                 inflight_rows: int = 0, passes: int = 0):
+                 rows_this_tick: int = 0, passes: int = 0):
         super().__init__(
             f"{info} (queue_depth={queue_depth}, "
-            f"inflight_rows={inflight_rows}, passes={passes})"
+            f"rows_this_tick={rows_this_tick}, passes={passes})"
         )
         self.info = info
         self.queue_depth = queue_depth
-        self.inflight_rows = inflight_rows
+        self.rows_this_tick = rows_this_tick
         self.passes = passes
 
 

@@ -414,17 +414,13 @@ class _NativeNetworkedSession(_NativeSessionBase):
                         lib.ggrs_sess_handle_wire(self._h, ep, wire, len(wire), now)
         lib.ggrs_sess_poll(self._h, now)
         # drain-free tick (P2PSession._pump_checksums' native twin):
-        # resolve host-ready desync checksums on the pump, prefetch the
-        # oldest in-flight one, stay two advances behind the capture
-        # frontier so no mid-correction value can bind early (spectator
-        # sessions share this pump but have no checksum lane)
+        # bind desync checksums on the pump and prefetch the oldest
+        # in-flight one, emitting nothing; stay two advances behind the
+        # capture frontier so no mid-correction value can bind early
+        # (spectator sessions share this pump but have no checksum lane)
         pcr = getattr(self, "_pending_checksum_report", None)
         if pcr is not None and self.desync_detection.enabled and len(pcr):
-            self._pending_checksum_report.flush(
-                force=False,
-                emit=self._emit_checksum_report,
-                max_serial=self._advance_serial - 2,
-            )
+            pcr.bind_and_prefetch(max_serial=self._advance_serial - 2)
         self._send_all()
 
     def _send_all(self) -> None:
@@ -608,14 +604,15 @@ class NativeP2PSession(_NativeNetworkedSession):
             # flush BEFORE this tick's advance: a report captured at tick t
             # covers a frame whose correcting rollback may have been in tick
             # t's request list — PendingChecksumReport reads the value only
-            # once the caller fulfilled those requests, i.e. by now
+            # once the caller fulfilled those requests, i.e. by now.
+            # Reports publish at the interval tick only, as in P2PSession
             interval = self.desync_detection.interval
-            force = self.current_frame % interval == interval - 1
-            blocked = self._pending_checksum_report.flush(
-                force, self._emit_checksum_report
-            )
-            if blocked:
-                self.drain_blocked_ticks += 1
+            pcr = self._pending_checksum_report
+            if self.current_frame % interval == interval - 1:
+                if pcr.flush(self._emit_checksum_report):
+                    self.drain_blocked_ticks += 1
+            else:
+                pcr.bind_and_prefetch()
         self._advance_serial += 1
         requests = self._advance_native(self.clock.now_ms())
         if self.desync_detection.enabled:

@@ -23,9 +23,12 @@ full window (docs/DESIGN.md "Depth-adaptive dispatch").
 Lifecycle: admission control (`max_sessions`, typed HostFull rejection),
 idle-session eviction and disconnect GC driven by the injectable Clock,
 and graceful drain (stop admitting, flush the fence, checkpoint the
-stacked worlds via utils/checkpoint). Backpressure: when the device
-window is full (`max_inflight_rows`), ready sessions queue in arrival
-order and the host reports queue depth.
+stacked worlds via utils/checkpoint). Backpressure is decided by count,
+never by device readiness: a host tick dispatches at most
+`max_inflight_rows` session rows, the rest of the ready sessions queue in
+arrival order for the next tick and the host reports queue depth; across
+ticks the device core's fence keeps at most `async_inflight` megabatches
+outstanding and blocks on the oldest past that.
 
 Telemetry rides the PR 2 obs registry: sessions active/evicted/rejected,
 megabatch-size histogram, cross-session occupancy, admission-queue wait
@@ -97,17 +100,6 @@ def _backend_refs():
         _BACKEND_REFS = (SnapshotRef, _LazyChecksum)
     return _BACKEND_REFS
 
-
-def _array_is_ready(arr) -> bool:
-    global _ARRAY_IS_READY
-    if _ARRAY_IS_READY is None:
-        from ..tpu.backend import _array_is_ready as impl
-
-        _ARRAY_IS_READY = impl
-    return _ARRAY_IS_READY(arr)
-
-
-_ARRAY_IS_READY = None
 
 # the "no env rows for this group" sentinel (shared: the dispatch loop
 # must not build a (0, []) default per megabatch pass)
@@ -261,16 +253,17 @@ class SessionHost:
                  resident_ticks: int = 16, sdc_audit_every: int = 0,
                  wedge_limit_ticks: int = 256,
                  drive_failure_limit: int = 3,
-                 shed_after_stall_ticks: int = 256,
-                 strict_invariants: bool = False,
                  journal_dir: Optional[str] = None,
                  journal_fsync_every: int = 0,
                  journal_segment_bytes: int = 1 << 18):
-        """`max_inflight_rows`: the device-window budget — session tick
-        rows admitted past the fence before ready sessions start queuing
-        (default: 2 full megabatches' worth). `idle_timeout_ms`: sessions
-        with no submitted input / advanced frame for this long are
-        evicted (0 disables). `warmup=True` compiles every megabatch
+        """`max_inflight_rows`: the per-tick row budget — session tick
+        rows one host tick dispatches (megabatches, adopts and drafts
+        together) before the remaining ready sessions queue for the next
+        tick (default: 2 full megabatches' worth). A count, reset at the
+        start of every tick and at every full fence retirement; never a
+        function of how fast the device finished. `idle_timeout_ms`:
+        sessions with no submitted input / advanced frame for this long
+        are evicted (0 disables). `warmup=True` compiles every megabatch
         bucket (the full row x depth grid under depth routing) before
         the first attach. `depth_routing=True` groups ready sessions by
         rollback depth and dispatches one megabatch per occupied depth
@@ -282,8 +275,10 @@ class SessionHost:
         one pass per message type over the union of every session's
         datagrams — instead of N per-message `poll_remote_clients`
         loops; False pins the legacy per-session pump (the parity
-        suite's reference). `async_inflight` defaults to 4 megabatches
-        (was 2): a wider fence keeps the steady-state tick from ever
+        suite's reference). `async_inflight`: the device core's fence —
+        at most this many megabatches outstanding across ticks; one
+        more dispatch blocks on the oldest (span tpu/async_fence).
+        Default 4: a wide fence keeps the steady-state tick from
         blocking on the oldest dispatch while the checksum ledger drains
         off the pump pass.
 
@@ -348,14 +343,11 @@ class SessionHost:
         bit-exactly; `drive_failure_limit` LIFETIME resident-drive
         failures DEGRADE the host to its dispatch-per-tick twin instead
         of crashing (bit-identical, slower — a device whose runtime
-        keeps failing is hardware-suspect, so the fallback is sticky). `shed_after_stall_ticks`
-        of a wedged fence (ready queue pinned at a full device window)
-        sheds admission — attach raises HostFull — until the stall
-        clears. `wedge_limit_ticks` bounds the always-on invariant
+        keeps failing is hardware-suspect, so the fallback is sticky).
+        `wedge_limit_ticks` bounds the always-on invariant
         monitors (lane progress, confirmed-watermark monotonicity,
         mailbox accounting), which record typed InvariantViolations
-        with forensics (`strict_invariants=True` raises them
-        instead).
+        with forensics.
 
         DURABLE INPUT JOURNAL (docs/DESIGN.md "Durable recovery"):
         `journal_dir` journals every p2p lane's CONFIRMED input rows to
@@ -451,7 +443,7 @@ class SessionHost:
         )
         self._m_queue_depth = _reg.gauge(
             "ggrs_host_queue_depth",
-            "ready sessions waiting on the device-window budget",
+            "ready sessions waiting on the per-tick row budget",
         )
         self._m_queue_wait = _reg.histogram(
             "ggrs_host_queue_wait_ticks",
@@ -465,8 +457,6 @@ class SessionHost:
         self._audit_every = sdc_audit_every
         self.wedge_limit_ticks = wedge_limit_ticks
         self.drive_failure_limit = drive_failure_limit
-        self.shed_after_stall_ticks = shed_after_stall_ticks
-        self.strict_invariants = strict_invariants
         # durable input journal (docs/DESIGN.md "Durable recovery")
         self._journal_dir = journal_dir
         self._journal_fsync_every = journal_fsync_every
@@ -495,8 +485,6 @@ class SessionHost:
         self.audit_mismatches = 0
         self._resident_degraded = False
         self._drive_failures = 0
-        self._shed_admission = False
-        self._stall_ticks = 0
         self.degrades = 0
         self._m_quarantines = _reg.counter(
             "ggrs_slot_quarantines_total",
@@ -516,7 +504,7 @@ class SessionHost:
         self._m_degraded = _reg.counter(
             "ggrs_degraded_mode_total",
             "degradation-ladder steps taken (resident loop falling back "
-            "to dispatch-per-tick, admission shed under a fence stall)",
+            "to dispatch-per-tick)",
             ("mode",),
         )
         self._m_invariants = _reg.counter(
@@ -637,14 +625,6 @@ class SessionHost:
         if self._draining:
             self._reject()
             raise HostFull("host is draining: not admitting sessions")
-        if self._shed_admission:
-            # degradation ladder: a wedged fence sheds new admissions
-            # BEFORE the backlog wedges the hosted fleet
-            self._reject()
-            raise HostFull(
-                "host is shedding admission: device fence stalled for "
-                f"{self._stall_ticks} ticks at a full inflight window"
-            )
         if not self._free_slots:
             self._reject()
             raise HostFull(
@@ -743,15 +723,6 @@ class SessionHost:
                     1,
                     min(self._resident_cadence, det.interval - 2),
                 )
-        if kind == "p2p":
-            # hosted lanes publish checksum reports at the interval-
-            # forced flush ONLY (resolution still rides the pump pass):
-            # publish timing is then a pure function of the frame
-            # counter, not of when device values became host-ready — a
-            # resident host's lazier harvest cadence would otherwise
-            # shift report datagrams on the seeded wire and fork the
-            # fault stream away from its dispatch-per-tick twin's
-            session.checksum_publish = "interval"
         lane = _Lane(
             key, session, slot, kind, n_players, local_handles,
             max_prediction, self.clock.now_ms(),
@@ -913,11 +884,6 @@ class SessionHost:
         if self._draining:
             self._reject()
             raise HostFull("host is draining: not admitting env blocks")
-        if self._shed_admission:
-            self._reject()
-            raise HostFull(
-                "host is shedding admission: device fence stalled"
-            )
         if num_envs < 1 or num_envs > len(self._free_slots):
             self._reject()
             raise HostFull(
@@ -965,7 +931,7 @@ class SessionHost:
 
     @property
     def queue_depth(self) -> int:
-        """Ready sessions still waiting on the device-window budget."""
+        """Ready sessions still waiting on the per-tick row budget."""
         return len(self._ready)
 
     # ------------------------------------------------------------------
@@ -984,7 +950,7 @@ class SessionHost:
     def tick(self) -> Dict[Any, List[Event]]:
         """One host cycle: pump every session's sockets, advance each
         ready session, coalesce their tick rows into megabatches under
-        the device-window budget, then run eviction/GC. Returns the
+        the per-tick row budget, then run eviction/GC. Returns the
         events each session surfaced this tick, keyed by host key."""
         with GLOBAL_TRACER.span("host/tick", absolute=True):
             out = self._tick_impl()
@@ -997,6 +963,9 @@ class SessionHost:
 
     def _tick_impl(self) -> Dict[Any, List[Event]]:
         self._tick_index += 1
+        # the per-tick row budget starts from zero: every scheduling
+        # decision below reads this count, never device readiness
+        self.device.begin_tick()
         events: Dict[Any, List[Event]] = {}
         tel = GLOBAL_TELEMETRY
 
@@ -1034,13 +1003,16 @@ class SessionHost:
                         if type(ev).__name__ == "DesyncDetected":
                             self.desyncs_observed += 1
 
-        # 1b. drain pass: retire ready fence entries and resolve every
-        # host-ready checksum batch OFF the tick path — with the batched
-        # checksum pump in the sessions, the steady-state tick never
-        # blocks on a device->host transfer (drain_blocked_ticks == 0).
-        # A HarvestTimeout (fault seam / real readback stall) is
-        # transient by contract: the values still exist on device, so
-        # this tick's drain is skipped and the next pass resolves them.
+        # 1b. drain pass: copy every checksum batch the fence retired
+        # OFF the tick path — with the batched checksum pump in the
+        # sessions, the steady-state tick never blocks on a device->host
+        # transfer (drain_blocked_ticks == 0). The copy decides no value
+        # a session sees: reports publish at the interval-forced flush. A
+        # HarvestTimeout (fault seam / real readback stall) is transient
+        # by contract: the values still exist on device, so this tick's
+        # drain is skipped and the next pass resolves them. SDC audits
+        # launched by earlier ticks resolve here (this tick's launch
+        # comes later, in step 3c).
         with GLOBAL_TRACER.span(
             "host/drain", absolute=True,
             feed=self._m_tax_drain if tel.enabled else None,
@@ -1048,8 +1020,7 @@ class SessionHost:
             try:
                 if self.fault_seam is not None:
                     self.fault_seam.before_harvest("drain")
-                self.device.ledger.drain_ready()
-                self.device.poll_retired()
+                self.device.drain_retired()
             except HarvestTimeout:
                 self.harvest_timeouts += 1
                 if tel.enabled:
@@ -1135,7 +1106,7 @@ class SessionHost:
         # journal never records a value a rollback could still change)
         self._pump_journals()
 
-        # 3. dispatch megabatches under the device-window budget (env
+        # 3. dispatch megabatches under the per-tick row budget (env
         # blocks still dispatch synchronously; in resident mode session
         # lanes never enter the ready queue, so this is env-only there)
         with GLOBAL_TRACER.span("host/dispatch", absolute=True):
@@ -1148,7 +1119,7 @@ class SessionHost:
         # same bucket grid) so their empty megabatch rows become standing
         # drafts the arrival tick can adopt. AFTER the confirmed
         # dispatches and capped by the budget they left over: draft work
-        # fills genuinely idle device window, it never crowds a ready
+        # fills the budget's leftover only, it never crowds a ready
         # session's row out of this tick
         if self._spec is not None and not self._draining:
             self._launch_drafts()
@@ -1159,33 +1130,8 @@ class SessionHost:
         if self._audit_every:
             self._maybe_audit()
 
-        # 3d. degradation ladder, fence-stall arm: a ready queue pinned
-        # at a full device window for `shed_after_stall_ticks` sheds
-        # admission until the stall clears
-        if self._ready and self.device.inflight_rows >= self.max_inflight_rows:
-            self._stall_ticks += 1
-            if (
-                self.shed_after_stall_ticks
-                and not self._shed_admission
-                and self._stall_ticks >= self.shed_after_stall_ticks
-            ):
-                self._shed_admission = True
-                self.degrades += 1
-                if tel.enabled:
-                    self._m_degraded.labels("shed_admission").inc()
-                    tel.record(
-                        "host_degraded", mode="shed_admission",
-                        stall_ticks=self._stall_ticks,
-                    )
-        else:
-            self._stall_ticks = 0
-            if self._shed_admission:
-                self._shed_admission = False
-                if tel.enabled:
-                    tel.record("host_admission_restored")
-
         with GLOBAL_TRACER.span("host/lifecycle", absolute=True):
-            # 3e. always-on invariant monitors (cheap: a handful of
+            # 3d. always-on invariant monitors (cheap: a handful of
             # integer compares per lane)
             self._check_invariants()
 
@@ -1630,10 +1576,10 @@ class SessionHost:
         device = self.device
         core = device.core
         # the budget the confirmed dispatches left over this tick: draft
-        # rows fill idle window only — a saturated device has no bubbles
-        # to fill, so skip rather than add inflight work real sessions
-        # will queue behind next tick
-        budget = self.max_inflight_rows - device.poll_retired()
+        # rows fill idle window only — a spent budget has no bubbles to
+        # fill, so skip rather than add work real sessions would queue
+        # behind
+        budget = self.max_inflight_rows - device.rows_this_tick
         if budget <= 0:
             return
         entries: List[Tuple[int, np.ndarray]] = []
@@ -1853,21 +1799,19 @@ class SessionHost:
         if GLOBAL_TELEMETRY.enabled:
             self._m_sdc_audits.inc(len(entries))
 
-    def _resolve_audits(self, block: bool = False) -> None:
-        """Resolve host-ready audit batches (all of them when `block`):
-        a (reference replay, live world) checksum mismatch is silent
-        data corruption — quarantine the slot with reason sdc_audit."""
+    def _resolve_audits(self) -> None:
+        """Resolve every pending audit batch, blocking if need be: a
+        (reference replay, live world) checksum mismatch is silent data
+        corruption — quarantine the slot with reason sdc_audit. Called
+        by the drain pass, before this tick's audits launch, and by a
+        flush: an audit resolves by age, never by device readiness."""
         if not self._pending_audits:
             return
         from ..ops.fixed_point import combine_checksum
 
-        remaining = []
-        for pending in self._pending_audits:
-            out, metas = pending
+        pending_audits, self._pending_audits = self._pending_audits, []
+        for out, metas in pending_audits:
             ref_hi, ref_lo, live_hi, live_lo, ring_hi, ring_lo = out
-            if not block and not _array_is_ready(ref_hi):
-                remaining.append(pending)
-                continue
             rh, rl = np.asarray(ref_hi), np.asarray(ref_lo)
             lh, ll = np.asarray(live_hi), np.asarray(live_lo)
             qh, ql = np.asarray(ring_hi), np.asarray(ring_lo)
@@ -1905,7 +1849,6 @@ class SessionHost:
                         verdicts=verdicts,
                     )
                 self.quarantine(key, "sdc_audit", frame=frame)
-        self._pending_audits = remaining
 
     # ------------------------------------------------------------------
     # always-on invariant monitors
@@ -1932,8 +1875,6 @@ class SessionHost:
         )
         if len(self.invariant_trips) < 256:
             self.invariant_trips.append(err)
-        if self.strict_invariants:
-            raise err
 
     def _check_invariants(self) -> None:
         """The cheap always-on monitors — the bug class the WAN soak
@@ -2215,8 +2156,8 @@ class SessionHost:
 
     def _pump_device(self) -> None:
         """Coalesce the ready queue's head rows into megabatches, oldest
-        arrivals first, until the device window is full or the queue is
-        empty. One row per session per megabatch preserves each session's
+        arrivals first, until this tick's row budget is spent or the
+        queue is empty. One row per session per megabatch preserves each session's
         in-order request stream; a session with a second staged row
         (sparse-saving keepalive) keeps its queue position.
 
@@ -2233,9 +2174,9 @@ class SessionHost:
         fold into the same groups — env step rows join the fast group,
         snapshot/restore rows their depth bucket — so one dispatch
         carries training AND interactive rows. Env rows are synchronous
-        training traffic (env.step blocks on this tick): when the
-        inflight budget is exhausted they retire the fence and dispatch
-        anyway rather than queue."""
+        training traffic (env.step blocks on this tick): when the row
+        budget is spent they retire the fence and dispatch anyway rather
+        than queue."""
         core = self.device.core
         # env-staged rows for this pass: gkey -> [max last_active, rows]
         env_groups: Dict[Any, List] = {}
@@ -2251,7 +2192,7 @@ class SessionHost:
         adopts = self._adopts_scratch
         groups = self._groups_scratch
         while self._ready or env_groups:
-            budget = self.max_inflight_rows - self.device.poll_retired()
+            budget = self.max_inflight_rows - self.device.rows_this_tick
             if budget <= 0:
                 if not env_groups:
                     break
@@ -2506,16 +2447,16 @@ class SessionHost:
             passes += 1
             if passes >= max_passes and self._ready:
                 depth = len(self._ready)
-                inflight = self.device.inflight_rows
+                rows = self.device.rows_this_tick
                 if GLOBAL_TELEMETRY.enabled:
                     GLOBAL_TELEMETRY.record(
                         "host_drain_stalled", reason=reason,
-                        queue_depth=depth, inflight_rows=inflight,
+                        queue_depth=depth, rows_this_tick=rows,
                         passes=passes,
                     )
                 raise DrainStalled(
                     f"{reason}: ready queue failed to flush",
-                    queue_depth=depth, inflight_rows=inflight,
+                    queue_depth=depth, rows_this_tick=rows,
                     passes=passes,
                 )
         try:
@@ -2523,7 +2464,7 @@ class SessionHost:
         except DeviceDispatchFailed as exc:
             self._recover_drive_failure(exc)
             self.device.block_until_ready()
-        self._resolve_audits(block=True)
+        self._resolve_audits()
 
     def _save_checkpoint(self, path: str) -> None:
         """device.save behind the harvest-timeout recovery contract: a
@@ -2624,7 +2565,7 @@ class SessionHost:
             "disconnect_gced": self.sessions_gced,
             "desyncs_observed": self.desyncs_observed,
             "queue_depth": len(self._ready),
-            "inflight_rows": dev.inflight_rows,
+            "rows_this_tick": dev.rows_this_tick,
             "max_inflight_rows": self.max_inflight_rows,
             "megabatches": dev.megabatches,
             "rows_dispatched": dev.rows_dispatched,
@@ -2641,7 +2582,6 @@ class SessionHost:
             "device_faults": self.device_faults,
             "harvest_timeouts": self.harvest_timeouts,
             "invariant_trips": len(self.invariant_trips),
-            "shedding_admission": self._shed_admission,
             # durable input journal (absent when no lane journals, so
             # old readers stay compatible)
             **(

@@ -24,7 +24,7 @@ surviving session re-adopted AT ITS OLD SLOT with endpoint timers rebased
 so the blackout cannot fire spurious disconnects).
 
 The degradation ladder, in order of increasing violence: backpressure
-(queue on the device-window budget) → spillover (sibling host) → evict
+(queue on the per-tick row budget) → spillover (sibling host) → evict
 (idle/disconnect GC) → drain (graceful, checkpointed). docs/DESIGN.md
 "Fleet operations" has the full handshake diagram.
 """

@@ -161,16 +161,6 @@ class P2PSession:
         # monotonic advance counter: stamps checksum-report captures so
         # the pump-side flush stays behind the capture frontier
         self._advance_serial = 0
-        # checksum-report publish policy: "ready" (default) emits on the
-        # pump pass as soon as a value is host-ready; "interval" defers
-        # EMISSION to the interval-forced flush while the pump still
-        # binds/prefetches (PendingChecksumReport.bind_and_prefetch) —
-        # publish timing is then a pure function of the frame counter,
-        # not of dispatch cadence. SessionHost sets "interval" on every
-        # hosted p2p lane so a resident (mailbox-driven) host puts
-        # bit-identical bytes on a seeded lossy wire as its
-        # dispatch-per-tick twin.
-        self.checksum_publish = "ready"
         # ticks whose interval-forced checksum flush had to BLOCK on a
         # device transfer (the host tax the pump-side drain removes);
         # plain int always maintained, registry counter behind enabled
@@ -523,26 +513,20 @@ class P2PSession:
         }
 
     def _pump_checksums(self) -> None:
-        """Opportunistic, non-blocking drain of pending desync-detection
-        reports on the pump pass: resolve the host-ready ones, prefetch
+        """Opportunistic, non-blocking half of desync-detection reporting
+        on the pump pass: bind the pending reports' getters and prefetch
         the oldest still-in-flight one, so the interval-forced flush in
         _check_checksum_send_interval finds the bytes already moved and
         the tick path never blocks on a checksum transfer in steady
-        state. Entries captured within the last two advances are left
-        untouched (max_serial): their frame's correcting rollback may
-        still sit in an unfulfilled — or, hosted, un-dispatched —
-        request list, and binding the getter early would publish a
-        mid-correction checksum."""
+        state. Nothing is emitted here: reports publish at the interval
+        tick only, so the wire stream is a function of the frame counter
+        and never of when device values became host-ready. Entries
+        captured within the last two advances are left unbound
+        (max_serial): their frame's correcting rollback may still sit in
+        an unfulfilled — or, hosted, un-dispatched — request list."""
         pcr = self._pending_checksum_report
         if len(pcr):
-            if self.checksum_publish == "interval":
-                pcr.bind_and_prefetch(max_serial=self._advance_serial - 2)
-            else:
-                pcr.flush(
-                    force=False,
-                    emit=self._emit_checksum_report,
-                    max_serial=self._advance_serial - 2,
-                )
+            pcr.bind_and_prefetch(max_serial=self._advance_serial - 2)
 
     def disconnect_player(self, player_handle: PlayerHandle) -> None:
         """(src/sessions/p2p_session.rs:430-456)"""
@@ -860,17 +844,16 @@ class P2PSession:
         # at tick t covers a frame whose *correcting* rollback may still be
         # in tick t's (unfulfilled) request list — PendingChecksumReport
         # reads the value on a later tick, once the cell is final.
-        force = current % interval == interval - 1
-        if self.checksum_publish == "interval" and not force:
-            # deterministic publish: the advance-side opportunistic flush
-            # binds/prefetches only — emission waits for the forced tick,
-            # so the wire stream is independent of dispatch cadence
+        # Reports publish at the interval tick only (the reference's
+        # cadence): between them the advance binds/prefetches, so the
+        # wire stream is independent of dispatch cadence.
+        if current % interval == interval - 1:
+            blocked = self._pending_checksum_report.flush(
+                self._emit_checksum_report
+            )
+        else:
             self._pending_checksum_report.bind_and_prefetch()
             blocked = 0
-        else:
-            blocked = self._pending_checksum_report.flush(
-                force=force, emit=self._emit_checksum_report
-            )
         if blocked:
             # the pump-side drain (_pump_checksums) exists to keep this
             # zero: a nonzero rate means the tick path still pays device

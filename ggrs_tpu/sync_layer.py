@@ -156,11 +156,11 @@ class PendingChecksumReport:
     slot): under the async dispatch pipeline a checksum may still be
     in-flight on the device when the next observation interval arrives,
     and the old single-slot design silently dropped the unflushed report.
-    Reports drain in capture (frame) order, emitting every host-ready
-    value in one pass; a not-yet-ready head starts a background prefetch
-    and stops the drain — nothing forces a device sync until `force`
-    bounds the delay to one desync interval. Reports whose ring slot was
-    reused before the first read are dropped, as before."""
+    Between interval ticks bind_and_prefetch binds getters and starts a
+    background copy of the oldest in-flight value, emitting nothing; the
+    interval tick's flush emits every report in capture (frame) order.
+    Reports whose ring slot was reused before the first read are
+    dropped, as before."""
 
     # outstanding-report bound: ~two ring rotations of observations. Past
     # it the oldest report's slot is long reused and it would drop at
@@ -200,9 +200,9 @@ class PendingChecksumReport:
 
     def capture(self, frame: Frame, cell: GameStateCell, serial: int = 0) -> None:
         """`serial` stamps the capturing tick (a monotonic advance
-        counter): non-forced flushes can then skip entries whose
-        capturing tick's requests may not be fulfilled yet (see
-        `max_serial` below)."""
+        counter): bind_and_prefetch can then skip entries whose
+        capturing tick's requests may not be fulfilled yet (its
+        `max_serial`)."""
         self._pending.append([frame, cell, None, serial])
         while len(self._pending) > self.MAX_PENDING:
             self._pending.popleft()
@@ -232,19 +232,24 @@ class PendingChecksumReport:
         self._pending = bound
 
     def bind_and_prefetch(self, max_serial: Optional[int] = None) -> None:
-        """The resolve-only half of flush() — DETERMINISTIC-PUBLISH mode
-        (hosted sessions, `checksum_publish == "interval"`): bind getters
-        for every old-enough entry and start a background prefetch on the
-        head, so the interval-forced flush later finds the bytes already
-        moved — but emit NOTHING. Report emission then happens at fixed
-        interval ticks regardless of when device values became
-        host-ready, which keeps the wire byte-stream independent of
-        dispatch cadence — the property that lets a resident
+        """The resolve-only half of flush(), run between interval ticks:
+        bind getters for every old-enough entry and start a background
+        prefetch on the head, so the interval-forced flush later finds
+        the bytes already moved — but emit NOTHING. Report emission then
+        happens at fixed interval ticks regardless of when device values
+        became host-ready, which keeps the wire byte-stream independent
+        of dispatch cadence — the property that lets a resident
         (mailbox-driven) host put bit-identical traffic on a seeded
-        lossy network as its dispatch-per-tick twin. Getters still
-        waiting on an UNDISPATCHED batch (a resident fill cycle's
-        future) are left alone: prefetching those would force the very
-        driver dispatch the mailbox exists to defer."""
+        lossy network as its dispatch-per-tick twin.
+
+        `max_serial` (the pump pass): only entries whose capture serial
+        is <= it are bound — a report captured at tick t covers a frame
+        whose *correcting* rollback may still sit in tick t's
+        unfulfilled (or, hosted, un-dispatched) request list, so the
+        pump stays a couple of advances behind the capture frontier.
+        Getters still waiting on an UNDISPATCHED batch (a resident fill
+        cycle's future) are left alone: prefetching those would force
+        the very driver dispatch the mailbox exists to defer."""
         self._bind(max_serial)
         for _frame, _cell, getter, _serial in self._pending:
             if getter is None:
@@ -256,34 +261,19 @@ class PendingChecksumReport:
                         prefetch()
                 return
 
-    def flush(self, force: bool, emit, max_serial: Optional[int] = None) -> int:
-        """emit(frame, checksum) is called at most once per captured report,
-        in capture order. Returns the number of reports that were resolved
-        while NOT host-ready — i.e. forced resolutions that blocked on a
-        device transfer (the drain the pump-side flush exists to make
-        zero in steady state).
-
-        `max_serial` (pump-side, non-forced drains): only entries whose
-        capture serial is <= it are bound/resolved — a report captured at
-        tick t covers a frame whose *correcting* rollback may still sit
-        in tick t's unfulfilled (or, hosted, un-dispatched) request list,
-        so an opportunistic mid-run flush must stay a couple of advances
-        behind the capture frontier; the interval-forced flush passes
-        None and drains everything, exactly as before."""
-        self._bind(max_serial)
+    def flush(self, emit) -> int:
+        """The interval tick's publish: bind and resolve every captured
+        report, calling emit(frame, checksum) at most once each, in
+        capture order. Returns the number of reports that were resolved
+        while NOT host-ready — i.e. that blocked on a device transfer
+        (the drain the pump-side bind_and_prefetch exists to make zero
+        in steady state)."""
+        self._bind(None)
         blocked = 0
         while self._pending:
-            frame, _cell, getter, serial = self._pending[0]
-            if getter is None:  # still inside the serial guard
-                return blocked
+            frame, _cell, getter, _serial = self._pending.popleft()
             if not getattr(getter, "ready", True):
-                if not force:
-                    prefetch = getattr(getter, "prefetch", None)
-                    if callable(prefetch):
-                        prefetch()
-                    return blocked
                 blocked += 1
-            self._pending.popleft()
             checksum = getter()
             if checksum is not None:
                 emit(frame, checksum)

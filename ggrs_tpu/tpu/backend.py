@@ -105,6 +105,10 @@ class _ChecksumBatch:
         self._los = los
         self._np: Optional[Tuple[np.ndarray, np.ndarray]] = None
         self._prefetched = False
+        # set by the async fence that admitted this batch's dispatch once
+        # it has waited on that dispatch or a later one: from then on a
+        # host copy never waits on the device (ChecksumLedger.drain_retired)
+        self._retired = False
         self._ledger = ledger
         ledger.register(self)
 
@@ -166,18 +170,20 @@ class ChecksumLedger:
         if len(self._pending) > self.MAX_PENDING:
             del self._pending[: -self.MAX_PENDING]
 
-    def drain_ready(self) -> int:
-        """Non-blocking drain for the pump pass (the drain-free tick):
-        resolve every pending batch whose device arrays are already
-        host-ready — a host-memory copy, no transfer wait — and start a
-        background host copy on the oldest still-executing batch so the
-        next pass (or a forced flush) finds its bytes moved. Returns the
-        number of batches still pending."""
+    def drain_retired(self) -> int:
+        """Non-blocking copy-ahead for the host's drain pass (the
+        drain-free tick): copy to host memory every pending batch the
+        async fence has marked retired — it already waited on that
+        dispatch or a later one, so the copy never waits on the device —
+        and start a background host copy on the oldest batch still in
+        flight, so a forced flush finds its bytes moved. Decided by the
+        fence's count, never by device readiness. Returns the number of
+        batches still pending."""
         still: List[_ChecksumBatch] = []
         for b in self._pending:
             if b._np is not None:
                 continue
-            if b.ready:
+            if b._retired:
                 b._store(b._his, b._los)
             else:
                 still.append(b)
@@ -1950,10 +1956,13 @@ class MultiSessionDeviceCore:
         # reuse is safe because the fence proves the dispatch that read
         # a buffer retired before the pool rotates back to it)
         self._stage_pools: dict = {}
-        # async fence over megabatches: (result handle, live row count);
-        # inflight_rows is the host's backpressure signal
+        # async fence over megabatches: (result handle, checksum batch or
+        # None), oldest first, bounded by COUNT (async_inflight) — never
+        # by device readiness; the fence marks each batch it retires.
+        # rows_this_tick counts the rows dispatched since the host's last
+        # begin_tick() or full retirement: the host's backpressure budget
         self._inflight: "_deque" = _deque()
-        self.inflight_rows = 0
+        self.rows_this_tick = 0
         self.megabatches = 0
         self.rows_dispatched = 0
         _reg = GLOBAL_TELEMETRY.registry
@@ -2375,19 +2384,33 @@ class MultiSessionDeviceCore:
                 # exactly the fast-path counter the smoke gate asserts)
                 self.core._m_depth.observe(nslots)
                 self.core._m_waste.inc((self.core.window - nslots) * n)
-        self._note_inflight(his, n)
-        return _ChecksumBatch(his, los, self.ledger), bucket
+        batch = _ChecksumBatch(his, los, self.ledger)
+        self._note_inflight(his, n, batch)
+        return batch, bucket
 
-    def _note_inflight(self, handle, n_rows: int) -> None:
+    def _note_inflight(self, handle, n_rows: int, batch=None) -> None:
         """Same fence discipline as TpuRollbackBackend._note_inflight:
         admit the dispatch, then block on the OLDEST once more than
-        async_inflight megabatches are outstanding."""
-        self._inflight.append((handle, n_rows))
-        self.inflight_rows += n_rows
+        async_inflight megabatches are outstanding, and mark that
+        dispatch's checksum batch retired. The only rule that bounds
+        work in flight across ticks; within a tick the host's budget
+        reads rows_this_tick."""
+        self._inflight.append((handle, batch))
+        self.rows_this_tick += n_rows
         while len(self._inflight) > self.async_inflight:
-            oldest, rows = self._inflight.popleft()
+            oldest, retired = self._inflight.popleft()
             self._fence_wait(oldest)
-            self.inflight_rows -= rows
+            if retired is not None:
+                retired._retired = True
+
+    def begin_tick(self) -> None:
+        """Open a host tick: the per-tick row count starts from zero."""
+        self.rows_this_tick = 0
+
+    def drain_retired(self) -> int:
+        """The ledger's copy-ahead of every checksum batch the fence has
+        retired (ChecksumLedger.drain_retired)."""
+        return self.ledger.drain_retired()
 
     def _fence_wait(self, handle) -> None:
         """Block on device work this core dispatched: the in-flight
@@ -2396,15 +2419,6 @@ class MultiSessionDeviceCore:
         feed = self._m_fence_stall if GLOBAL_TELEMETRY.enabled else None
         with GLOBAL_TRACER.span("tpu/async_fence", absolute=True, feed=feed):
             jax.block_until_ready(handle)
-
-    def poll_retired(self) -> int:
-        """Drop already-retired megabatches from the fence without
-        blocking; returns the rows still in flight (the host's
-        backpressure budget reads this)."""
-        while self._inflight and _array_is_ready(self._inflight[0][0]):
-            _, rows = self._inflight.popleft()
-            self.inflight_rows -= rows
-        return self.inflight_rows
 
     # ------------------------------------------------------------------
     # speculative bubble-filling (serve/speculation.py drives this):
@@ -2600,8 +2614,9 @@ class MultiSessionDeviceCore:
             depth = max(advance_count - matched, 1)
             self.core._m_depth.observe(depth)
             self.core._m_waste.inc(self.core.window - depth)
-        self._note_inflight(his, 1)
-        return _ChecksumBatch(his, los, self.ledger)
+        batch = _ChecksumBatch(his, los, self.ledger)
+        self._note_inflight(his, 1, batch)
+        return batch
 
     # ------------------------------------------------------------------
     # SDC audit lane (serve/host.py's sampled double-compute drives it)
@@ -2903,8 +2918,8 @@ class MultiSessionDeviceCore:
             mbox.observe_drive(n_rows, vticks)
             self.core._m_depth.observe(nslots)
             self.core._m_waste.inc((self.core.window - nslots) * n_rows)
-        self._note_inflight(his, n_rows)
         batch = _ChecksumBatch(his, los, self.ledger)
+        self._note_inflight(his, n_rows, batch)
         if future is not None:
             future.batch = batch
         return batch
@@ -3243,8 +3258,11 @@ class MultiSessionDeviceCore:
         # always observe the canonical (fully ticked) worlds
         self.drive_mailbox()
         jax.block_until_ready(self.states)
+        for _, batch in self._inflight:
+            if batch is not None:
+                batch._retired = True
         self._inflight.clear()
-        self.inflight_rows = 0
+        self.rows_this_tick = 0
 
     def retire_fence(self) -> None:
         """block_until_ready for the tick path: the same retirement of

@@ -137,7 +137,7 @@ def main():
             host.checkpoint(ckpt)
         clock.advance(FRAME_MS)
     host.device.block_until_ready()
-    host._resolve_audits(block=True)
+    host._resolve_audits()
 
     # --- 1. survivors keep serving -----------------------------------
     victim_keys = set(victims)

@@ -333,7 +333,7 @@ def test_fen001_host_never_touches_device_internals():
         "class SessionHost:\n"
         "    def hack(self):\n"
         "        self.device._inflight.clear()\n"
-        "        self.device.inflight_rows = 0\n"
+        "        self.device.rows_this_tick = 0\n"
         "    def hack_tuple(self):\n"
         "        # the codebase's canonical write form for the stacked\n"
         "        # worlds must not slip through as tuple unpacking\n"
@@ -344,7 +344,7 @@ def test_fen001_host_never_touches_device_internals():
     clean = {"ggrs_tpu/serve/host.py": (
         "class SessionHost:\n"
         "    def ok(self):\n"
-        "        return self.device.poll_retired()\n"
+        "        self.device.begin_tick()\n"
     )}
     assert rules_fired(clean, ["fence"])[0] == []
 

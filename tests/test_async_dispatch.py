@@ -208,10 +208,11 @@ class FakeCell:
 
 
 def test_pending_report_drains_in_frame_order():
-    """Reports queue while their device values are in flight and drain in
-    capture order — a ready report NEVER jumps an unready older one (the
-    peer would see out-of-order frames), and nothing forces a sync until
-    `force` bounds the delay."""
+    """Reports queue while their device values are in flight: between
+    interval ticks nothing is emitted and nothing forces a sync, but the
+    oldest in-flight value's copy starts; the interval flush emits every
+    report in capture order — a ready report NEVER jumps an unready older
+    one (the peer would see out-of-order frames)."""
     rep = PendingChecksumReport()
     getters = {f: FakeGetter(f * 1000 + 7) for f in (10, 20, 30)}
     for f in (10, 20, 30):
@@ -219,20 +220,19 @@ def test_pending_report_drains_in_frame_order():
     emitted = []
     emit = lambda frame, checksum: emitted.append((frame, checksum))
 
-    rep.flush(force=False, emit=emit)
+    rep.bind_and_prefetch()
     assert emitted == []  # head in flight: nothing emitted, no sync forced
     assert getters[10].prefetches > 0  # ...but its copy was started
 
     getters[20].ready = True  # a LATER report landing first
-    rep.flush(force=False, emit=emit)
+    rep.bind_and_prefetch()
     assert emitted == []  # must not jump the queue past frame 10
+    assert getters[20].prefetches == 0  # only the head's copy starts
 
     getters[10].ready = True
-    rep.flush(force=False, emit=emit)
-    assert emitted == [(10, 10007), (20, 20007)]  # one batch, in order
-
-    rep.flush(force=True, emit=emit)  # force bounds the tail's delay
+    blocked = rep.flush(emit)  # the interval tick: everything, in order
     assert emitted == [(10, 10007), (20, 20007), (30, 30007)]
+    assert blocked == 1  # frame 30's value was still in flight
     assert len(rep) == 0
 
 
@@ -247,7 +247,7 @@ def test_pending_report_drops_reused_slot():
     rep.capture(6, FakeCell(6, live))
     live.ready = True
     emitted = []
-    rep.flush(force=False, emit=lambda f, c: emitted.append((f, c)))
+    rep.flush(lambda f, c: emitted.append((f, c)))
     assert emitted == [(6, 2)]
 
 

@@ -111,7 +111,7 @@ def build_fleet(*, resident, sessions=16, ticks=60, seed=11, loss=0.0,
             host.tick()
             clock.advance(FRAME_MS)
     host.device.block_until_ready()
-    host._resolve_audits(block=True)
+    host._resolve_audits()
     return host, matches, injector, desyncs
 
 

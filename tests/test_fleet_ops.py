@@ -575,9 +575,11 @@ def test_drain_stalled_is_typed_and_recorded():
         key = host.attach(solo_session(net, "w"))
         for h in (0, 1):
             host.submit_input(key, h, b"\x01")
-        # stage a row, then wedge the scheduler so it can never dispatch
-        real_poll = host.device.poll_retired
-        host.device.poll_retired = lambda: host.max_inflight_rows
+        # stage a row, then wedge the scheduler so it can never dispatch:
+        # a row budget of zero admits nothing, however often the fence
+        # retires
+        budget = host.max_inflight_rows
+        host.max_inflight_rows = 0
         host.tick()
         assert host.queue_depth == 1
         with pytest.raises(DrainStalled) as exc_info:
@@ -592,7 +594,7 @@ def test_drain_stalled_is_typed_and_recorded():
         ]
         assert events and events[-1]["queue_depth"] == 1
         # un-wedged, the same drain flushes clean
-        host.device.poll_retired = real_poll
+        host.max_inflight_rows = budget
         summary = host.drain()
         assert summary["queue_depth"] == 0
     finally:

@@ -113,6 +113,8 @@ def test_native_sessions_independent_across_threads():
     import threading
     import time
 
+    from ggrs_tpu import PredictionThreshold
+
     s0 = make_session(19411, 19412, 0, native=True)
     s1 = make_session(19412, 19411, 1, native=True)
     games = {0: GameStub(), 1: GameStub()}
@@ -129,9 +131,20 @@ def test_native_sessions_independent_across_threads():
                 time.sleep(0.001)
             assert sess.current_state() == SessionState.RUNNING
             for f in range(30):
-                sess.poll_remote_clients()
-                sess.add_local_input(handle, bytes([(f * (handle + 2)) % 7]))
-                games[handle].handle_requests(sess.advance_frame())
+                deadline = time.monotonic() + 10
+                while True:
+                    sess.poll_remote_clients()
+                    sess.add_local_input(handle, bytes([(f * (handle + 2)) % 7]))
+                    try:
+                        games[handle].handle_requests(sess.advance_frame())
+                        break
+                    except PredictionThreshold:
+                        # the prediction window is full while the peer's
+                        # thread is descheduled: wait for its inputs and
+                        # retry the frame, as a real client does
+                        if time.monotonic() > deadline:
+                            raise
+                        time.sleep(0.001)
                 time.sleep(0.001)
         except Exception as e:  # surfaced below; a thread must not die silently
             errors.append((handle, e))
@@ -146,8 +159,9 @@ def test_native_sessions_independent_across_threads():
         t.join(timeout=60)
     assert not errors, errors
     assert not any(t.is_alive() for t in threads), "drive thread hung"
-    # both peers simulated and their confirmed prefixes agree
-    confirmed = min(max(games[0].history) - 3, max(games[1].history) - 3, 25)
+    # both peers simulated and the prefix both confirmed agrees (frames
+    # past a peer's last confirmed frame may still hold predictions)
+    confirmed = min(s.sync_layer.last_confirmed_frame for s in (s0, s1))
     assert confirmed >= 10
     for f in range(1, confirmed + 1):
         assert games[0].history[f] == games[1].history[f], f"frame {f}"

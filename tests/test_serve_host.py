@@ -216,18 +216,100 @@ def test_backpressure_queues_ready_sessions_in_arrival_order():
     for key in keys:
         for h in (0, 1):
             host.submit_input(key, h, b"\x01")
-    # pin the device window shut: nothing retires, so the budget is 0 and
-    # every ready session must queue
-    real_poll = host.device.poll_retired
-    host.device.poll_retired = lambda: host.max_inflight_rows
+    # four ready sessions, a budget of two rows a tick: the first two
+    # arrivals dispatch, the last two queue in arrival order
     host.tick()
-    assert host.queue_depth == 4
-    assert all(host._lanes[k].rows for k in keys)
-    # reopen the window: queued rows dispatch in arrival order
-    host.device.poll_retired = real_poll
+    assert host.queue_depth == 2
+    assert list(host._ready) == keys[2:]
+    assert not any(host._lanes[k].rows for k in keys[:2])
+    assert all(host._lanes[k].rows for k in keys[2:])
+    # the next tick's fresh budget dispatches the queued rows
     host.tick()
     assert host.queue_depth == 0
     assert all(host._lanes[k].current_frame == 1 for k in keys)
+
+
+def _readiness_probe_trace(ready: bool, monkeypatch, ticks: int = 48):
+    """Drive a small lossy hosted fleet with the device's readiness
+    probe forced to `ready`; returns per tick, per lane, the current
+    frame and desync checksum history, and the host's queue depth."""
+    from ggrs_tpu.serve.loadgen import (
+        build_matches,
+        held_scripts,
+        sync_fleet,
+    )
+    from ggrs_tpu.tpu import backend
+
+    monkeypatch.setattr(backend, "_array_is_ready", lambda arr: ready)
+    clock = FakeClock()
+    net = InMemoryNetwork(clock, latency_ms=20, jitter_ms=5, loss=0.05,
+                          seed=11)
+    # a budget below the fleet's rows per tick, so some ticks queue
+    host = make_host(clock, max_sessions=4, max_inflight_rows=3)
+    matches = build_matches(host, net, clock, sessions=4,
+                            players_cycle=(2,), desync_interval=4, seed=11)
+    sync_fleet(host, matches, clock)
+    scripts = held_scripts(matches, ticks, seed=11)
+    trace = []
+    for t in range(ticks):
+        for m, keys in enumerate(matches):
+            for k, key in enumerate(keys):
+                host.submit_input(key, k, bytes([scripts[(m, k)][t]]))
+        host.tick()
+        clock.advance(16)
+        lanes = tuple(
+            (host.session(key).current_frame,
+             tuple(sorted(host.session(key).local_checksum_history.items())))
+            for keys in matches for key in keys
+        )
+        trace.append((lanes, host.queue_depth))
+    monkeypatch.undo()
+    return trace
+
+
+def test_schedule_independent_of_device_readiness(monkeypatch):
+    """The hosted tick's schedule is a function of the host's inputs and
+    its tick count: whether the device reports its work finished or not
+    changes no lane's frame, no checksum report and no queue depth."""
+    never = _readiness_probe_trace(False, monkeypatch)
+    always = _readiness_probe_trace(True, monkeypatch)
+    for t, (a, b) in enumerate(zip(never, always)):
+        assert a == b, f"tick {t}"
+    assert any(depth for _, depth in never)  # the budget did bind
+    assert any(hist for lanes, _ in never for _, hist in lanes)
+
+
+def test_drain_copies_exactly_the_fence_retired_batches(monkeypatch):
+    """The drain pass's checksum copy-ahead follows the fence's count:
+    it copies every batch whose dispatch the fence has retired, and only
+    those stay pending that are still in the fence's window — with the
+    device never reporting any work finished."""
+    from ggrs_tpu.tpu import backend
+
+    monkeypatch.setattr(backend, "_array_is_ready", lambda arr: False)
+    clock = FakeClock()
+    net = InMemoryNetwork(clock)
+    host = make_host(clock, max_sessions=4, async_inflight=2)
+    keys = [host.attach(solo_session(net, f"s{i}")) for i in range(4)]
+    device = host.device
+    copied = 0
+    for t in range(12):
+        for key in keys:
+            for h in (0, 1):
+                host.submit_input(key, h, bytes([(t * 5 + h + key) % 16]))
+        host.tick()
+        clock.advance(16)
+        retired = [b for b in device.ledger._pending
+                   if b._np is None and b._retired]
+        device.drain_retired()
+        assert all(b._np is not None for b in retired), f"tick {t}"
+        copied += len(retired)
+        window = {id(b) for _, b in device._inflight if b is not None}
+        for b in device.ledger._pending:
+            assert b._np is None and not b._retired, f"tick {t}"
+            assert id(b) in window, f"tick {t}"
+        assert len(device._inflight) <= device.async_inflight
+    assert copied > 0
 
 
 # ----------------------------------------------------------------------
@@ -303,15 +385,12 @@ def test_graceful_drain_flushes_fence_and_checkpoints(tmp_path):
     host = make_host(clock, max_sessions=3, max_inflight_rows=1)
     keys = [host.attach(solo_session(net, f"s{i}")) for i in range(3)]
     drive_solo(host, keys[0], host.session(keys[0]), 3)
-    # stage rows that CANNOT dispatch (window pinned shut), then drain:
-    # it must flush them anyway
+    # stage more rows than one tick's budget (one row) dispatches, then
+    # drain: it must flush the queued ones anyway
     for key in keys:
         for h in (0, 1):
             host.submit_input(key, h, b"\x03")
-    real_poll = host.device.poll_retired
-    host.device.poll_retired = lambda: host.max_inflight_rows
     host.tick()
-    host.device.poll_retired = real_poll
     assert host.queue_depth > 0
     path = str(tmp_path / "host.npz")
     summary = host.drain(checkpoint_path=path)

@@ -69,8 +69,8 @@ def test_served_tick_tiles_into_layer_spans(traced):
     sessions = [host.session(k) for keys in matches for k in keys]
     start = sum(s.current_frame for s in sessions)
     _drive(host, matches, clock, ticks)
-    # how many sessions advance on a tick depends on the device window's
-    # backpressure (readiness of in-flight work), so count what happened
+    # how many sessions advance on a tick depends on the per-tick row
+    # budget and the network's timing, so count what happened
     advanced_frames = sum(s.current_frame for s in sessions) - start
     spans = _values(traced, "ggrs_span_ms")
     assert spans["host/tick"]["count"] == ticks
@@ -91,16 +91,13 @@ def test_served_tick_tiles_into_layer_spans(traced):
     assert not any(k.endswith("/session/advance") for k in spans)
 
 
-def test_host_core_fence_observed(traced, monkeypatch):
+def test_host_core_fence_observed(traced):
     """With one megabatch allowed in flight, every further dispatch waits
     on the oldest: the wait is a tpu/async_fence span and a
     ggrs_async_fence_stall_ms observation, one clock pair for both. The
-    device is made to report no work finished early, so the drain pass
-    retires nothing and the waits happen however fast the device is."""
-    from ggrs_tpu.tpu import backend
-
+    fence counts megabatches, so the waits happen however fast the
+    device is."""
     host, matches, clock = _fleet(4, async_inflight=1)
-    monkeypatch.setattr(backend, "_array_is_ready", lambda arr: False)
     traced.reset()
     _drive(host, matches, clock, 20)
     stall = _values(traced, "ggrs_async_fence_stall_ms")[""]

@@ -274,8 +274,9 @@ def test_session_parity_batched_vs_legacy(use_native):
 
 
 def test_pending_checksum_report_serial_guard():
-    """Non-forced flushes must not bind entries captured within the
-    serial guard — their correcting rollback may be unfulfilled."""
+    """Binding between interval ticks must not bind entries captured
+    within the serial guard — their correcting rollback may be
+    unfulfilled."""
 
     class Cell:
         def __init__(self, frame):
@@ -291,12 +292,11 @@ def test_pending_checksum_report_serial_guard():
     old = Cell(10)
     pcr.capture(10, old, serial=5)
     pcr.capture(20, young, serial=9)
-    emitted = []
-    pcr.flush(force=False, emit=lambda f, c: emitted.append(f), max_serial=7)
-    assert emitted == [10]
+    pcr.bind_and_prefetch(max_serial=7)
     assert old.bound == 1 and young.bound == 0
-    # the forced flush (max_serial=None) drains everything, as before
-    pcr.flush(force=True, emit=lambda f, c: emitted.append(f))
+    # the interval flush binds and emits everything, in capture order
+    emitted = []
+    pcr.flush(lambda f, c: emitted.append(f))
     assert emitted == [10, 20]
     assert young.bound == 1
 
